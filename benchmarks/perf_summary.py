@@ -2,8 +2,7 @@
 from __future__ import annotations
 
 from benchmarks.common import row
-from benchmarks.roofline import load
-from repro.launch.mesh import ICI_BW, PEAK_FLOPS_BF16
+from benchmarks.roofline import PEAKS, load
 
 HILLCLIMBS = {
     ("deepseek-moe-16b", "train_4k"): [
@@ -54,8 +53,8 @@ def run():
                     f"perf/{arch}/{label}",
                     0.0,
                     (
-                        f"compute={r['flops'] / PEAK_FLOPS_BF16:.3e}s;"
-                        f"collective={r['collective_bytes']['total'] / ICI_BW:.3e}s;"
+                        f"compute={r['flops'] / PEAKS.bf16_flops:.3e}s;"
+                        f"collective={r['collective_bytes']['total'] / PEAKS.ici_bytes_per_s:.3e}s;"
                         f"peakGiB={r['mem']['peak_bytes'] / 2**30:.2f}"
                     ),
                 )

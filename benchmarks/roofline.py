@@ -15,7 +15,10 @@ from typing import Dict
 
 from benchmarks.common import row
 from repro import configs as cfglib
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
+
+# the dry run lowers for the production mesh, a TPU v5e slice
+PEAKS = chip_peaks("TPU v5 lite")
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts", "dryrun.jsonl")
 
@@ -131,10 +134,10 @@ def analytic_bytes(arch: str, shape: str, mesh_model: int = 16,
 
 
 def terms(rec, n_chips: int) -> Dict[str, float]:
-    comp = rec["flops"] / PEAK_FLOPS_BF16
-    mem_hlo = rec["hbm_bytes"] / HBM_BW
-    memt = analytic_bytes(rec["arch"], rec["shape"]) / HBM_BW
-    coll = rec["collective_bytes"]["total"] / ICI_BW
+    comp = rec["flops"] / PEAKS.bf16_flops
+    mem_hlo = rec["hbm_bytes"] / PEAKS.hbm_bytes_per_s
+    memt = analytic_bytes(rec["arch"], rec["shape"]) / PEAKS.hbm_bytes_per_s
+    coll = rec["collective_bytes"]["total"] / PEAKS.ici_bytes_per_s
     dom = max(("compute", comp), ("memory", memt), ("collective", coll),
               key=lambda kv: kv[1])
     mf = model_flops(rec["arch"], rec["shape"]) / n_chips

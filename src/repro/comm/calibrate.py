@@ -38,7 +38,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.comm.cost import AlphaBeta, LinkTopo, _pattern
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 
 DEFAULT_LENGTHS = (1 << 12, 1 << 14, 1 << 16, 1 << 18)
 
@@ -189,7 +189,7 @@ def time_collective(
         )
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=P(dp_spec, None),

@@ -9,9 +9,9 @@ kernel:
 * **fusability matrix** — :func:`fusable` and its factors
   (:func:`config_fusable` / :func:`wire_fusable` / :func:`shape_fusable`):
   which (sparsifier x selector x codec x collective x shape) combinations
-  the fused pipeline reproduces bit-for-bit. Everything else stays on the
-  unfused path; routing is always a per-leaf decision, never a global
-  switch.
+  the fused pipeline selects exactly as the unfused one does. Everything
+  else stays on the unfused path; routing is always a per-leaf decision,
+  never a global switch.
 * **pricing** — :class:`ThroughputTable`, the measured-throughput table
   behind ``fastpath="auto"``: analytic HBM-traffic defaults
   (:func:`fused_hbm_bytes` / :func:`unfused_hbm_bytes`, the same columns
@@ -22,9 +22,10 @@ kernel:
 * **runtime routing** — :func:`fused_compact_select`, the drop-in
   replacement for ``repro.core.compact.compact_select`` on fusable
   configs. The kernel's exactness certificate gates a ``lax.cond``
-  fallback to the dense path, so the routed result is bit-for-bit equal
-  to the unfused one *unconditionally*; the certificate only decides
-  which pipeline computed it.
+  fallback to the dense path, so on the same inputs the routed payload
+  equals the unfused one; the certificate only decides which pipeline
+  computed it. Whole training runs can still drift apart by ulps: the
+  fused and unfused steps are different programs (docs/comm.md below).
 
 ``DistConfig.fastpath`` / ``DistributedSim(fastpath=...)`` / the train
 CLI's ``--fastpath`` accept ``"off"`` (default, historical path),
@@ -323,14 +324,15 @@ def leaf_fused(
 def fused_compact_select(scfg, st, g, k: int, *, interpret=None):
     """Fused replacement for ``compact.compact_select`` on fusable configs.
 
-    Returns the same ``(a, vals [k], idx [k])`` triple, bit-for-bit: the
-    compact posterior statistics are scattered to the dense layout the
-    kernel reads (state inputs, not the mask/masked-gradient
-    intermediates the fusion eliminates), the pipeline emits the payload
-    from score registers, and the exactness certificate ``lax.cond``s to
-    the dense path whenever the candidate budget cannot prove the
-    selection exact. Callers must have checked :func:`config_fusable`
-    and :func:`shape_fusable`."""
+    Returns ``(a, vals [k], idx [k], fell_back)``: the same triple as the
+    dense path plus a bool scalar saying whether the exactness certificate
+    failed and the dense path computed the payload. The compact posterior
+    statistics are scattered to the dense layout the kernel reads (state
+    inputs, not the mask/masked-gradient intermediates the fusion
+    eliminates), the pipeline emits the payload from score registers, and
+    the certificate ``lax.cond``s to the dense path whenever the candidate
+    budget cannot prove the selection exact. Callers must have checked
+    :func:`config_fusable` and :func:`shape_fusable`."""
     from repro.core import compact as C
     from repro.kernels import ops
 
@@ -367,7 +369,7 @@ def fused_compact_select(scfg, st, g, k: int, *, interpret=None):
         return v.astype(a.dtype), i
 
     vals, idx = jax.lax.cond(ok, lambda _: (vals, idx), _dense, None)
-    return a, vals, idx
+    return a, vals, idx, jnp.logical_not(ok)
 
 
 def make_score_fn(interpret: Optional[bool] = None):

@@ -1,40 +1,22 @@
-"""Version compatibility helpers.
+"""Mesh construction, in one place for the whole repo.
 
-The codebase targets the current jax API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``); this module backfills the handful
-of call sites that moved between jax 0.4.x and newer releases so the repo
-runs on both. Import from here instead of feature-testing inline.
+``make_mesh`` marks every axis ``Auto``: the train step shards its batch
+with ``with_sharding_constraint``, which only accepts Auto axes, while
+``jax.make_mesh`` without ``axis_types`` gives Explicit ones.
 """
 from __future__ import annotations
 
 import jax
-
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-
-else:  # jax <= 0.4.x: experimental namespace, check_vma was check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_04(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=check_vma,
-        )
+from jax.sharding import AxisType
 
 
-def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` without ``axis_types`` (absent in jax <= 0.4.x;
-    explicit axis types are only needed by the newer sharding-in-types
-    work, which this repo does not rely on)."""
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types, over ``devices`` (default:
+    every device JAX sees)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes),
+        names,
+        axis_types=(AxisType.Auto,) * len(names),
+        devices=devices,
+    )

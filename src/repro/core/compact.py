@@ -56,7 +56,7 @@ def compact_init(length: int, k: int, dtype=jnp.float32) -> CompactState:
     )
 
 
-def _apply_k_dyn(a, vals, idx, k_dyn, capacity: int):
+def apply_k_dyn(a, vals, idx, k_dyn, capacity: int):
     """Keep only the first ``k_dyn`` of the descending-sorted payload.
 
     ``lax.top_k`` (and the bit-identical fused pipeline) returns values in
@@ -91,7 +91,7 @@ def compact_select(
     ``fastpath`` routes fusable configs through the Pallas fused
     select→encode pipeline (:mod:`repro.comm.fastpath`): ``"on"``/
     ``"auto"`` fuse when the (kind, selector, shape, f32 state) admits
-    it — the result is bit-for-bit identical (a runtime exactness
+    it — the selection is identical (a runtime exactness
     certificate falls back to this dense path otherwise; a non-f32 state
     would score in a different precision, so it never fuses) — while
     ``None``/``"off"`` is the historical dense selection. ``"auto"``
@@ -127,10 +127,10 @@ def compact_select(
                 )
             )
         ):
-            a, vals, idx = fp.fused_compact_select(cfg, st, g, k)
+            a, vals, idx, _ = fp.fused_compact_select(cfg, st, g, k)
             if k_dyn is None:
                 return a, vals, idx
-            return _apply_k_dyn(a, vals, idx, k_dyn, k)
+            return apply_k_dyn(a, vals, idx, k_dyn, k)
     a = st.eps + g.astype(st.eps.dtype)
     if cfg.kind == "none":
         raise ValueError("'none' bypasses compact_select")
@@ -177,7 +177,7 @@ def compact_select(
         vals = a[idx] * (score[idx] > 0)
         if k_dyn is None:
             return a, vals, idx
-        return _apply_k_dyn(a, vals, idx, k_dyn, k)
+        return apply_k_dyn(a, vals, idx, k_dyn, k)
     if cfg.selector == "threshold":
         mask = sel_lib.threshold_topk_mask(score, k)
         vals, idx = sel_lib.mask_to_payload(mask, a, k)

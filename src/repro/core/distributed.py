@@ -39,7 +39,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import comm
-from repro.compat import shard_map
 from repro.core import compact as C
 from repro.core.selectors import sparsity_to_k
 from repro.core.sparsify import SparsifierConfig
@@ -83,7 +82,7 @@ class DistConfig:
     # fused select→encode fastpath (repro.comm.fastpath; train.py's
     # --fastpath): "off" (default) is the historical dense-selection path;
     # "on" routes every fusable leaf through the Pallas fused pipeline
-    # (bit-for-bit equivalent — a runtime exactness certificate falls back
+    # (the same selection — a runtime exactness certificate falls back
     # per call otherwise); "auto" fuses the leaves the measured-throughput
     # table prices faster, and resolves to "off" off-TPU where the kernels
     # run in interpret mode.
@@ -407,15 +406,15 @@ def sparsifier_state_shapes(plan, W: int, mesh, dp_axes, dtype):
     return shapes, specs
 
 
-def init_sparsifier_state(plan, W: int, mesh, dp_axes, dtype, shardings=None):
+def init_sparsifier_state(plan, W: int, mesh, dp_axes, dtype):
+    """Zero state, allocated in place under its specs — each worker's
+    error accumulator lands on that worker's devices, and the jitted step
+    sees the same input sharding on its first call as on every later one
+    (no second trace)."""
     shapes, specs = sparsifier_state_shapes(plan, W, mesh, dp_axes, dtype)
 
     def mk(s, spec):
-        if shardings is None:
-            return jnp.zeros(s.shape, s.dtype)
-        return jax.device_put(
-            jnp.zeros(s.shape, s.dtype), NamedSharding(mesh, spec)
-        )
+        return jnp.zeros(s.shape, s.dtype, device=NamedSharding(mesh, spec))
 
     return jax.tree.map(mk, shapes, specs), specs
 
@@ -432,12 +431,14 @@ def controller_state_specs(plan):
     )
 
 
-def init_controller_state(plan, dist: DistConfig):
+def init_controller_state(plan, dist: DistConfig, mesh):
     """(ControllerState tree mirroring ``plan``, PartitionSpec tree).
 
     Each leaf starts at the static-sparsity k clipped into the
     controller's per-leaf bounds; the plan must have been built with
-    ``dist`` so leaf capacities already sit at ``k_max``."""
+    ``dist`` so leaf capacities already sit at ``k_max``. The scalars are
+    replicated over ``mesh`` up front, as the round returns them, so the
+    jitted round does not trace again on its second call."""
     ctrl = dist.resolved_adaptive_k()
     if ctrl is None:
         raise ValueError("init_controller_state needs dist.adaptive_k")
@@ -449,7 +450,10 @@ def init_controller_state(plan, dist: DistConfig):
         )
 
     return (
-        jax.tree.map(mk, plan, is_leaf=_is_plan),
+        jax.device_put(
+            jax.tree.map(mk, plan, is_leaf=_is_plan),
+            NamedSharding(mesh, P()),
+        ),
         controller_state_specs(plan),
     )
 
@@ -482,7 +486,9 @@ def _ctrl_update(ctrl_cfg, ctrl_leaf, new_st, agg, p: LeafPlan, dp_axes,
 def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
               part_ctx=None, fused=False, k_dyn=None, weighting="worker"):
     """Local (worker x model-shard) view: g [1, *local], st with leading
-    [1(,1)] axes. Returns (agg local shard [*local], new state).
+    [1(,1)] axes. Returns (agg local shard [*local], new state, fell_back)
+    where ``fell_back`` is 1.0 when a fused leaf's exactness certificate
+    failed this round and the dense path picked the payload, else 0.0.
 
     All aggregation routes through :mod:`repro.comm`: the ``dense_allreduce``
     strategy psums the sparse-but-dense vector (uncompressed, exact); payload
@@ -491,10 +497,10 @@ def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
     (``coo_q8``) keep their residual in ``eps``.
 
     ``fused`` routes selection through the Pallas fused select→encode
-    pipeline (``compact_select(..., fastpath="on")`` +
+    pipeline (``comm.fastpath.fused_compact_select`` +
     ``codec.encode_fused`` — no dense score/mask/masked-gradient
-    intermediates, bit-for-bit equivalent) — callers only set it on
-    leaves the fusability matrix admits (see ``leaf_fastpath``).
+    intermediates) — callers only set it on leaves the fusability matrix
+    admits (see ``leaf_fastpath``).
 
     ``part_ctx`` (``(m, w_part)``, computed once per round by
     ``make_sparsify_aggregate`` from the shared schedule) makes the round
@@ -527,6 +533,7 @@ def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
     )
     if part_ctx is not None:
         m, w_part = part_ctx
+    fell_back = jnp.zeros((), jnp.float32)
     if scfg.kind == "none":
         if part_ctx is None:
             agg = jax.lax.pmean(
@@ -539,10 +546,15 @@ def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
             ).astype(gl.dtype)
         new = stl._replace(t=stl.t + 1)
     else:
-        a, vals, idx = C.compact_select(
-            scfg, stl, gl, p.k, k_dyn=k_dyn,
-            fastpath="on" if fused else None,
-        )
+        if fused:
+            a, vals, idx, fb = comm.fastpath.fused_compact_select(
+                scfg, stl, gl, p.k
+            )
+            fell_back = fb.astype(jnp.float32)
+            if k_dyn is not None:
+                a, vals, idx = C.apply_k_dyn(a, vals, idx, k_dyn, p.k)
+        else:
+            a, vals, idx = C.compact_select(scfg, stl, gl, p.k, k_dyn=k_dyn)
         omega = scfg.omega if part_ctx is None else w_part
         shard_mask = None if part_ctx is None else m
         coord = weighting == "coordinate"
@@ -609,12 +621,17 @@ def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
         sent_w=new.sent_w[None, None],
         t=new.t[None],
     )
-    return agg.reshape(p.local_shape).astype(g.dtype), new_out
+    return agg.reshape(p.local_shape).astype(g.dtype), new_out, fell_back
 
 
 def make_sparsify_aggregate(
     mesh, plan, param_specs, state_specs, dist: DistConfig, n_workers: int
 ):
+    """The sparsify+aggregate round as one ``shard_map`` over the mesh:
+    ``(grads, state[, ctrl]) -> (agg, state[, ctrl], fallbacks)``.
+    ``fallbacks`` counts the fused-leaf selections (leaf x device) whose
+    exactness certificate failed this round, so the fused path's dense
+    fallback is never silent (0 without fused leaves)."""
     dp = tuple(dist.dp_axes)
     dp_spec = dp if len(dp) > 1 else dp[0]
     dp_sizes = tuple(int(mesh.shape[a]) for a in dp)
@@ -729,33 +746,31 @@ def make_sparsify_aggregate(
                     )
         agg = jax.tree.unflatten(plan_def, [o[0] for o in outs])
         new_state = jax.tree.unflatten(plan_def, [o[1] for o in outs])
-        if ctrl is None:
-            return agg, new_state
-        new_ctrl = jax.tree.unflatten(plan_def, [
-            _ctrl_update(
-                ctrl_cfg, c, o[1], o[0], p, dp, model_axes, lo, hi
-            )
-            for o, c, p, (lo, hi) in zip(
-                outs, c_flat, plan_flat, leaf_bounds, strict=True
-            )
-        ])
-        return agg, new_state, new_ctrl
+        out = (agg, new_state)
+        if ctrl is not None:
+            out += (jax.tree.unflatten(plan_def, [
+                _ctrl_update(
+                    ctrl_cfg, c, o[1], o[0], p, dp, model_axes, lo, hi
+                )
+                for o, c, p, (lo, hi) in zip(
+                    outs, c_flat, plan_flat, leaf_bounds, strict=True
+                )
+            ]),)
+        fb = jnp.zeros((), jnp.float32)
+        if any(fused_flags):
+            fb = jax.lax.psum(sum(o[2] for o in outs), tuple(mesh.axis_names))
+        return (*out, fb)
 
     grads_in_specs = jax.tree.map(lambda s: P(dp_spec, *tuple(s)), param_specs)
-    if ctrl_cfg is None:
-        return shard_map(
-            lambda grads, state: rounds(grads, state),
-            mesh=mesh,
-            in_specs=(grads_in_specs, state_specs),
-            out_specs=(param_specs, state_specs),
-            check_vma=False,
-        )
-    ctrl_specs = controller_state_specs(plan)
-    return shard_map(
-        rounds,
-        mesh=mesh,
-        in_specs=(grads_in_specs, state_specs, ctrl_specs),
-        out_specs=(param_specs, state_specs, ctrl_specs),
+    in_specs = (grads_in_specs, state_specs)
+    out_specs = (param_specs, state_specs)
+    if ctrl_cfg is not None:
+        ctrl_specs = controller_state_specs(plan)
+        in_specs += (ctrl_specs,)
+        out_specs += (ctrl_specs,)
+    out_specs += (P(),)
+    return jax.shard_map(
+        rounds, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
 
@@ -916,6 +931,9 @@ def make_train_step(
     """train_step(params, opt_state, sp_state, batch) ->
     (params, opt_state, sp_state, metrics)
 
+    ``metrics["fastpath_fallbacks"]`` counts the fused-leaf selections
+    (leaf x device) that fell back to dense selection this step.
+
     With ``dist.adaptive_k`` set, ``sp_state`` is the *pair*
     ``(compact_state_tree, controller_state_tree)`` (see
     :func:`init_controller_state`) and metrics gain ``"adaptive_k"``, the
@@ -992,13 +1010,16 @@ def make_train_step(
         )
         if adaptive:
             cp_state, ctrl_state = sp_state
-            agg, new_cp, new_ctrl = spa(grads_w, cp_state, ctrl_state)
+            agg, new_cp, new_ctrl, fallbacks = spa(
+                grads_w, cp_state, ctrl_state
+            )
             new_sp = (new_cp, new_ctrl)
         else:
-            agg, new_sp = spa(grads_w, sp_state)
+            agg, new_sp, fallbacks = spa(grads_w, sp_state)
         new_params, new_opt = opt.update(agg, opt_state, params)
         metrics = {
             "loss": losses.mean(),
+            "fastpath_fallbacks": fallbacks,
             "comm_bytes": jnp.asarray(wire_meas, jnp.float32),
             "comm_bytes_predicted": jnp.asarray(wire_pred, jnp.float32),
         }

@@ -99,7 +99,7 @@ def threshold_topk_mask(
     if k <= 0:
         return jnp.zeros_like(score)
     if k >= score.shape[0]:
-        return jnp.ones_like(score)
+        return (score > 0).astype(score.dtype)
 
     hi0 = jnp.max(score)
     lo0 = jnp.zeros_like(hi0)

@@ -27,38 +27,54 @@ def _block_topm_kernel(score_ref, vals_ref, idx_ref, *, m):
     rowi = jax.lax.broadcasted_iota(jnp.int32, BLOCK, 0)
     colj = jax.lax.broadcasted_iota(jnp.int32, BLOCK, 1)
     flat = (i * SUBLANES + rowi) * LANES + colj  # global flat index
-    for r in range(m):  # static tiny unroll
+    # (1, m) register rows, stored once: Mosaic has no scalar VMEM stores
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+
+    def round_(r, carry):
+        s, row_v, row_i = carry
         cur = jnp.max(s)
         ismax = s == cur
         # first-match tie break: lowest flat index among maxima
         cand_idx = jnp.min(jnp.where(ismax, flat, jnp.iinfo(jnp.int32).max))
-        vals_ref[0, r] = cur
-        idx_ref[0, r] = cand_idx
-        s = jnp.where(flat == cand_idx, -jnp.inf, s)
+        here = slot == r
+        return (
+            jnp.where(flat == cand_idx, -jnp.inf, s),
+            jnp.where(here, cur, row_v),
+            jnp.where(here, cand_idx, row_i),
+        )
+
+    _, row_v, row_i = jax.lax.fori_loop(
+        0, m, round_,
+        (s, jnp.zeros((1, m), jnp.float32), jnp.zeros((1, m), jnp.int32)),
+    )
+    vals_ref[0] = row_v
+    idx_ref[0] = row_i
 
 
 def block_topk_candidates(
     score: jax.Array, m: int = 8, *, interpret: bool = False
 ) -> Tuple[jax.Array, jax.Array]:
-    """score [rows, 1024] -> (vals [rows//8, m], flat idx [rows//8, m])."""
+    """score [rows, 1024] -> (vals [rows//8, m], flat idx [rows//8, m]).
+
+    The kernel writes ``[nblk, 1, m]``: a (1, m) block must span the
+    array's last two dimensions for Mosaic to accept it."""
     rows, lanes = score.shape
     nblk = rows // SUBLANES
     grid = (nblk,)
     kernel = functools.partial(_block_topm_kernel, m=m)
-    return pl.pallas_call(
+    cand = pl.BlockSpec((1, 1, m), lambda i: (i, 0, 0))
+    vals, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec(BLOCK, lambda i: (i, 0))],
-        out_specs=(
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-        ),
+        out_specs=(cand, cand),
         out_shape=(
-            jax.ShapeDtypeStruct((nblk, m), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, m), jnp.int32),
+            jax.ShapeDtypeStruct((nblk, 1, m), jnp.float32),
+            jax.ShapeDtypeStruct((nblk, 1, m), jnp.int32),
         ),
         interpret=interpret,
     )(score)
+    return vals[:, 0], idx[:, 0]
 
 
 def hierarchical_topk(

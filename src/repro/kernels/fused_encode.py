@@ -24,8 +24,8 @@ Exactness: the candidate set provably contains the global top-k whenever
 no tile hides more than ``m`` coordinates scoring at-or-above the k-th
 selected value. :func:`select_from_candidates` returns an ``ok`` flag
 implementing exactly that certificate (conservative under ties); callers
-``lax.cond`` to the unfused path when it fails, so the pipeline is
-bit-for-bit equivalent to dense selection *unconditionally* — the
+``lax.cond`` to the unfused path when it fails, so on the same inputs
+the pipeline selects exactly what dense selection does — the
 certificate only decides which path computed the answer. See
 ``repro.comm.fastpath`` for the policy layer and
 ``docs/comm.md#the-fused-fastpath`` for the fusability matrix.
@@ -66,18 +66,38 @@ def _fused_kernel(
     rowi = jax.lax.broadcasted_iota(jnp.int32, BLOCK, 0)
     colj = jax.lax.broadcasted_iota(jnp.int32, BLOCK, 1)
     flat = (i * SUBLANES + rowi) * LANES + colj
-    s = score
-    for r in range(m):  # static tiny unroll
+    # candidates are gathered into (1, m) rows in registers and stored
+    # once per tile: Mosaic has no scalar stores to VMEM.
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+
+    def round_(r, carry):
+        s, row_s, row_v, row_i = carry
         cur = jnp.max(s)
         ismax = s == cur
         # first-match tie break: lowest flat index among maxima (matches
         # lax.top_k's stable ordering for the equivalence proof)
         cand = jnp.min(jnp.where(ismax, flat, jnp.iinfo(jnp.int32).max))
         onehot = flat == cand
-        cs_ref[0, r] = cur
-        cv_ref[0, r] = jnp.sum(jnp.where(onehot, a, 0.0))
-        ci_ref[0, r] = cand
-        s = jnp.where(onehot, -jnp.inf, s)
+        here = slot == r
+        return (
+            jnp.where(onehot, -jnp.inf, s),
+            jnp.where(here, cur, row_s),
+            jnp.where(here, jnp.sum(jnp.where(onehot, a, 0.0)), row_v),
+            jnp.where(here, cand, row_i),
+        )
+
+    _, row_s, row_v, row_i = jax.lax.fori_loop(
+        0, m, round_,
+        (
+            score,
+            jnp.zeros((1, m), jnp.float32),
+            jnp.zeros((1, m), jnp.float32),
+            jnp.zeros((1, m), jnp.int32),
+        ),
+    )
+    cs_ref[0] = row_s
+    cv_ref[0] = row_v
+    ci_ref[0] = row_i
 
 
 def fused_candidates(
@@ -96,7 +116,9 @@ def fused_candidates(
     """All inputs [rows, 1024] float32. Returns per-tile candidate triples
     ``(scores [nblk, m], values [nblk, m], flat idx [nblk, m])`` where
     ``nblk = rows // 8`` — the score is computed and consumed in-register,
-    never written back dense."""
+    never written back dense. The kernel writes ``[nblk, 1, m]`` so each
+    tile's output block spans the array's last two dimensions, as Mosaic
+    requires of a block narrower than (8, 128)."""
     rows, lanes = a.shape
     if lanes != LANES:
         raise ValueError(f"expected lane dim {LANES}, got {lanes}")
@@ -104,22 +126,23 @@ def fused_candidates(
         raise ValueError(f"rows must be a multiple of {SUBLANES}")
     nblk = rows // SUBLANES
     spec = pl.BlockSpec(BLOCK, lambda i: (i, 0))
-    cand = pl.BlockSpec((1, m), lambda i: (i, 0))
+    cand = pl.BlockSpec((1, 1, m), lambda i: (i, 0, 0))
     kernel = functools.partial(
         _fused_kernel, omega=omega, mu=mu, q=q, y=y, m=m
     )
-    return pl.pallas_call(
+    cs, cv, ci = pl.pallas_call(
         kernel,
         grid=(nblk,),
         in_specs=[spec, spec, spec, spec],
         out_specs=(cand, cand, cand),
         out_shape=(
-            jax.ShapeDtypeStruct((nblk, m), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, m), jnp.float32),
-            jax.ShapeDtypeStruct((nblk, m), jnp.int32),
+            jax.ShapeDtypeStruct((nblk, 1, m), jnp.float32),
+            jax.ShapeDtypeStruct((nblk, 1, m), jnp.float32),
+            jax.ShapeDtypeStruct((nblk, 1, m), jnp.int32),
         ),
         interpret=interpret,
     )(a, a_prev, s_prev, g_prev)
+    return cs[:, 0], cv[:, 0], ci[:, 0]
 
 
 def select_from_candidates(

@@ -9,18 +9,21 @@ bisection step,
     blockmax    = max over the whole vector       (for the initial bracket)
 
 The grid walks (8, 1024) VMEM tiles; scalar results accumulate into a
-(1, 1) output across sequential grid steps (TPU grid execution is
-sequential, so read-modify-write accumulation is well-defined).
+(1, 1) SMEM output across sequential grid steps (TPU grid execution is
+sequential, so read-modify-write accumulation is well-defined). Scalars
+live in SMEM because Mosaic cannot store a scalar to VMEM.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 1024
 SUBLANES = 8
 BLOCK = (SUBLANES, LANES)
+SCALAR = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _count_kernel(tau_ref, score_ref, count_ref):
@@ -28,7 +31,7 @@ def _count_kernel(tau_ref, score_ref, count_ref):
 
     @pl.when(i == 0)
     def _init():
-        count_ref[...] = jnp.zeros_like(count_ref)
+        count_ref[0, 0] = jnp.int32(0)
 
     tau = tau_ref[0, 0]
     c = jnp.sum((score_ref[...] >= tau).astype(jnp.int32))
@@ -40,7 +43,7 @@ def _max_kernel(score_ref, max_ref):
 
     @pl.when(i == 0)
     def _init():
-        max_ref[...] = jnp.full_like(max_ref, -jnp.inf)
+        max_ref[0, 0] = jnp.float32(-jnp.inf)
 
     m = jnp.max(score_ref[...])
     max_ref[0, 0] = jnp.maximum(max_ref[0, 0], m)
@@ -54,11 +57,8 @@ def count_above(
     return pl.pallas_call(
         _count_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec(BLOCK, lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        in_specs=[SCALAR, pl.BlockSpec(BLOCK, lambda i: (i, 0))],
+        out_specs=SCALAR,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         interpret=interpret,
     )(tau.reshape(1, 1), score)[0, 0]
@@ -71,7 +71,7 @@ def global_max(score: jax.Array, *, interpret: bool = False) -> jax.Array:
         _max_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec(BLOCK, lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        out_specs=SCALAR,
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
     )(score)[0, 0]

@@ -1,19 +1,24 @@
 """Training launcher.
 
-CPU/dev:    PYTHONPATH=src python -m repro.launch.train --arch paper-resnet-proxy \
-                --steps 50 --global-batch 8 --seq 64
-Production: run under a TPU runtime where ``jax.devices()`` exposes the
-            16x16 (or 2x16x16 with --multi-pod) slice; the same flags apply
-            with --mesh production.
+    PYTHONPATH=src python -m repro.launch.train --arch paper-resnet-proxy \
+        --steps 50 --global-batch 8 --seq 64
+
+One host: ``--mesh host`` (the default) builds a (data, model) mesh over
+``jax.devices()`` — one CPU device, one TPU chip, or the four chips of a
+v5e host, each chip a data-parallel worker unless ``--model-parallel``
+says otherwise. ``--mesh production`` is the 16x16 (2x16x16 with
+``--multi-pod``) slice the dry run lowers for.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs as cfglib
 from repro.checkpoint import restore, save
@@ -31,7 +36,8 @@ from repro.models import get_family
 from repro.optim import OptConfig, make_optimizer
 
 
-def _replan(dist, mesh, dp_axes, plan, step_fn, sp_state, mod, cfg, asm, t):
+def _replan(dist, mesh, dp_axes, plan, step_fn, sp_state, mod, cfg, asm, t,
+            jit_step):
     """Mid-training re-plan (--replan-every): probe the live collectives,
     fit a fresh alpha-beta model from the measured samples, re-run the
     per-leaf (codec x collective) planning at the k actually being sent,
@@ -95,13 +101,31 @@ def _replan(dist, mesh, dp_axes, plan, step_fn, sp_state, mod, cfg, asm, t):
     )
     for (c, s), n in sorted(picks.items()):
         print(f"replan:   {c}/{s}: {n} leaves", flush=True)
-    step = jax.jit(make_train_step(
+    step = jit_step(make_train_step(
         mod, cfg, dist, mesh, asm.param_specs, new_plan, asm.state_specs
     ))
     return new_plan, step
 
 
-def main():
+class TrainRun(NamedTuple):
+    """What one :func:`main` run saw."""
+
+    losses: List[float]  # per step
+    # per step: fused-leaf selections (leaf x device) whose exactness
+    # certificate failed, so the dense path chose the payload
+    fallbacks: List[float]
+    compile_seconds: float  # lowering + compiling the step
+    # steady seconds per step after the first, timed to block_until_ready
+    # (None for a one-step run)
+    step_seconds: Optional[float]
+    n_fused: int  # leaves on the fused select->encode path
+    n_leaves: int
+    compiled: Any  # the compiled step (HLO text, memory analysis)
+    params: Any  # final parameters
+    sp_state: Any  # final sparsifier state
+
+
+def main(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-resnet-proxy")
     ap.add_argument("--steps", type=int, default=50)
@@ -123,10 +147,11 @@ def main():
     ap.add_argument("--fastpath", default="off",
                     choices=["off", "on", "auto"],
                     help="fused Pallas select->encode pipeline: 'on' "
-                         "fuses every fusable leaf (bit-for-bit, with a "
-                         "runtime exactness fallback), 'auto' fuses the "
-                         "leaves the measured-throughput table prices "
-                         "faster (resolves to 'off' off-TPU)")
+                         "fuses every fusable leaf (with a runtime "
+                         "exactness fallback to dense selection; see "
+                         "docs/comm.md for how close it stays to 'off'), "
+                         "'auto' fuses the leaves the measured-throughput "
+                         "table prices faster (resolves to 'off' off-TPU)")
     ap.add_argument("--link-topo", default=None, metavar="SPEC",
                     help="per-dp-axis link model for auto-planning: "
                          "';'-separated 'class:alpha,beta' entries where "
@@ -186,7 +211,8 @@ def main():
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--resume", default=None)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    meshlib.enable_compile_cache()
 
     cfg = cfglib.get_config(args.arch)
     if args.smoke:
@@ -309,16 +335,22 @@ def main():
         )
     mod = get_family(cfg)
     asm = assemble(mod, cfg, dist, mesh)
-    params, _ = mod.init(jax.random.PRNGKey(0), cfg)
+    # initialized in place under the parameter specs, not on one device
+    params = jax.jit(
+        lambda key: mod.init(key, cfg)[0],
+        out_shardings=jax.tree.map(
+            lambda s: NamedSharding(mesh, s), asm.param_specs
+        ),
+    )(jax.random.PRNGKey(0))
     opt = make_optimizer(dist.optimizer)
-    opt_state = opt.init(params)
+    opt_state = jax.jit(opt.init)(params)
     sp_state, _ = init_sparsifier_state(
         asm.plan, W, mesh, dp_axes, jnp.float32
     )
     if adaptive_k is not None:
         from repro.core.distributed import init_controller_state
 
-        ctrl0, _ = init_controller_state(asm.plan, dist)
+        ctrl0, _ = init_controller_state(asm.plan, dist, mesh)
         sp_state = (sp_state, ctrl0)
     start = 0
     if args.resume:
@@ -331,7 +363,30 @@ def main():
         print(f"resumed from step {start}")
 
     pipe = TokenPipeline(cfg, args.global_batch, args.seq)
-    step_fn = jax.jit(asm.train_step)
+    # the step hands its state back where it found it, so the second call
+    # sees the first call's input shardings and reuses its compilation;
+    # what init left on one device (scalars, a restored checkpoint) is
+    # replicated over the mesh
+    state_shardings = jax.tree.map(
+        lambda x: (
+            x.sharding if isinstance(x.sharding, NamedSharding)
+            else NamedSharding(mesh, P())
+        ),
+        (params, opt_state, sp_state),
+    )
+    params, opt_state, sp_state = jax.device_put(
+        (params, opt_state, sp_state), state_shardings
+    )
+
+    def jit_step(fn):
+        return jax.jit(
+            fn,
+            out_shardings=(
+                *state_shardings, NamedSharding(mesh, P())
+            ),
+        )
+
+    step_fn = jit_step(asm.train_step)
     pred_b, meas_b = comm_round_bytes(asm.plan, dist, mesh)
     round_cost = comm_round_cost(asm.plan, dist, mesh)
     print(
@@ -364,26 +419,40 @@ def main():
         )
         for (c, s), n in sorted(picks.items()):
             print(f"comm:   auto-plan {c}/{s}: {n} leaves", flush=True)
-    if dist.resolved_fastpath() != "off":
-        from repro.core.distributed import LeafPlan, leaf_fastpath
+    from repro.core.distributed import LeafPlan, leaf_fastpath
 
-        leaves = jax.tree.leaves(
-            asm.plan, is_leaf=lambda x: isinstance(x, LeafPlan)
-        )
-        n_fused = sum(leaf_fastpath(p, dist) for p in leaves)
+    leaves = jax.tree.leaves(
+        asm.plan, is_leaf=lambda x: isinstance(x, LeafPlan)
+    )
+    n_fused = sum(leaf_fastpath(p, dist) for p in leaves)
+    if dist.resolved_fastpath() != "off":
         print(
             f"comm:   fastpath: {n_fused}/{len(leaves)} leaves fused",
             flush=True,
         )
     plan = asm.plan
-    t0 = time.time()
+    losses, fallbacks = [], []
     with mesh:
+        batch = pipe.batch_at(start)
+        t0 = time.perf_counter()
+        compiled = step_fn.lower(params, opt_state, sp_state, batch).compile()
+        compile_s = time.perf_counter() - t0
+        print(f"compile: {compile_s:.2f}s", flush=True)
+        t0 = time.perf_counter()
         for t in range(start, start + args.steps):
+            if t > start:
+                batch = pipe.batch_at(t)
             params, opt_state, sp_state, m = step_fn(
-                params, opt_state, sp_state, pipe.batch_at(t)
+                params, opt_state, sp_state, batch
             )
+            losses.append(m["loss"])
+            fallbacks.append(m["fastpath_fallbacks"])
+            if t == start:
+                # steady timing starts once the first step has finished
+                jax.block_until_ready(m)
+                t_steady = time.perf_counter()
             if t % args.log_every == 0 or t == start + args.steps - 1:
-                dt = time.time() - t0
+                dt = time.perf_counter() - t0
                 extra = (
                     f" k {float(m['adaptive_k']):7.1f}"
                     if "adaptive_k" in m else ""
@@ -401,14 +470,29 @@ def main():
             ):
                 plan, step_fn = _replan(
                     dist, mesh, dp_axes, plan, step_fn, sp_state,
-                    mod, cfg, asm, t,
+                    mod, cfg, asm, t, jit_step,
                 )
+        jax.block_until_ready((params, opt_state, sp_state))
+    step_s = None
+    if args.steps > 1:
+        step_s = (time.perf_counter() - t_steady) / (args.steps - 1)
     if args.checkpoint:
         save(args.checkpoint + "/params", params,
              metadata={"step": start + args.steps})
         save(args.checkpoint + "/opt", opt_state)
         save(args.checkpoint + "/sparsifier", sp_state)
         print(f"checkpointed to {args.checkpoint}")
+    return TrainRun(
+        losses=[float(x) for x in losses],
+        fallbacks=[float(x) for x in fallbacks],
+        compile_seconds=compile_s,
+        step_seconds=step_s,
+        n_fused=n_fused,
+        n_leaves=len(leaves),
+        compiled=compiled,
+        params=params,
+        sp_state=sp_state,
+    )
 
 
 if __name__ == "__main__":

@@ -1,125 +1,8 @@
-"""Hypothesis shim: the real library when installed, else a deterministic
-seeded-sampling fallback so the property tests still *run* (not skip) on a
-clean environment (ISSUE 1 satellite — the bare ``from hypothesis import``
-used to error the whole ``pytest -x`` collection).
-
-The fallback implements only the strategy surface this repo uses
-(``integers``, ``floats``, ``lists``, ``booleans``, ``sampled_from``) and
-draws ``max_examples`` pseudo-random samples from a fixed seed — weaker than
-hypothesis (no shrinking, no edge-case bias beyond the endpoints we inject)
-but the invariants are still exercised. Test modules import via::
+"""Hypothesis entry points for the property tests. Test modules import via::
 
     from _hyp import given, settings, st
 """
-from __future__ import annotations
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-__all__ = ["HAVE_HYPOTHESIS", "given", "settings", "st"]
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-
-    HAVE_HYPOTHESIS = True
-except ModuleNotFoundError:
-    HAVE_HYPOTHESIS = False
-
-    import inspect
-    import random
-
-    class _Strategy:
-        def __init__(self, draw, endpoints=()):
-            self.draw = draw
-            # endpoint samples are injected first (cheap edge-case bias)
-            self.endpoints = list(endpoints)
-
-    class _St:
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(
-                lambda rng: rng.randint(min_value, max_value),
-                endpoints=[min_value, max_value],
-            )
-
-        @staticmethod
-        def floats(
-            min_value=None,
-            max_value=None,
-            allow_nan=False,
-            allow_infinity=False,
-            width=64,
-            **_kw,
-        ):
-            lo = -1e6 if min_value is None else min_value
-            hi = 1e6 if max_value is None else max_value
-            return _Strategy(
-                lambda rng: rng.uniform(lo, hi), endpoints=[lo, hi, 0.0]
-            )
-
-        @staticmethod
-        def lists(elements, min_size=0, max_size=10, **_kw):
-            def draw(rng):
-                n = rng.randint(min_size, max_size)
-                return [elements.draw(rng) for _ in range(n)]
-
-            return _Strategy(draw)
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: rng.random() < 0.5)
-
-        @staticmethod
-        def sampled_from(elements):
-            seq = list(elements)
-            return _Strategy(lambda rng: seq[rng.randrange(len(seq))])
-
-    st = _St()
-
-    def settings(*, max_examples=20, **_kw):
-        def deco(fn):
-            fn._max_examples = max_examples
-            return fn
-
-        return deco
-
-    def given(*strategies, **kw_strategies):
-        def deco(fn):
-            # The last positional parameters are strategy-drawn; anything
-            # before them stays visible to pytest (parametrize / fixtures).
-            params = list(inspect.signature(fn).parameters)
-            free = [p for p in params if p not in kw_strategies]
-            n_fix = len(free) - len(strategies)
-            fixture_params, strat_names = free[:n_fix], free[n_fix:]
-
-            def wrapper(**fixture_kwargs):
-                rng = random.Random(0xE9)
-                n = getattr(wrapper, "_max_examples", 20)
-                ran = 0
-                # endpoint passes first (single-strategy case only: combined
-                # endpoint products explode for multi-arg tests)
-                if len(strategies) == 1 and not kw_strategies:
-                    for ep in strategies[0].endpoints:
-                        fn(**fixture_kwargs, **{strat_names[0]: ep})
-                        ran += 1
-                while ran < n:
-                    drawn = {
-                        nm: s.draw(rng)
-                        for nm, s in zip(strat_names, strategies, strict=True)
-                    }
-                    kw = {k: s.draw(rng) for k, s in kw_strategies.items()}
-                    fn(**fixture_kwargs, **drawn, **kw)
-                    ran += 1
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper.__signature__ = inspect.Signature(
-                [
-                    inspect.Parameter(
-                        p, inspect.Parameter.POSITIONAL_OR_KEYWORD
-                    )
-                    for p in fixture_params
-                ]
-            )
-            return wrapper
-
-        return deco
+__all__ = ["given", "settings", "st"]

@@ -160,12 +160,12 @@ def test_spa_disabled_controller_bit_for_bit_multidevice():
             with mesh:
                 if adaptive is None:
                     for _ in range(5):
-                        agg, state = jax.jit(spa)(grads, state)
+                        agg, state, _ = jax.jit(spa)(grads, state)
                         aggs.append(np.asarray(agg["w"]))
                 else:
-                    ctrl, _ = init_controller_state(plan, dist)
+                    ctrl, _ = init_controller_state(plan, dist, mesh)
                     for _ in range(5):
-                        agg, state, ctrl = jax.jit(spa)(
+                        agg, state, ctrl, _ = jax.jit(spa)(
                             grads, state, ctrl
                         )
                         aggs.append(np.asarray(agg["w"]))
@@ -229,7 +229,7 @@ def test_adaptive_spa_multidevice_adapts_and_compiles_once():
         state, specs = init_sparsifier_state(
             plan, 4, mesh, ("data",), jnp.float32
         )
-        ctrl, _ = init_controller_state(plan, dist)
+        ctrl, _ = init_controller_state(plan, dist, mesh)
         spa = make_sparsify_aggregate(
             mesh, plan, {"w": P(None)}, specs, dist, 4
         )
@@ -244,7 +244,7 @@ def test_adaptive_spa_multidevice_adapts_and_compiles_once():
         ks = []
         with mesh:
             for _ in range(6):
-                agg, state, ctrl = step(grads, state, ctrl)
+                agg, state, ctrl, _ = step(grads, state, ctrl)
                 ks.append(int(ctrl["w"].k))
         jax.block_until_ready(agg)
         print(json.dumps({

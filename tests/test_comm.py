@@ -185,9 +185,10 @@ def test_shard_form_matches_reference_single_device(cname, sname):
     mesh (axis size 1: the gather/psum are identities, so the shard-form
     plumbing — including the participation hook — is checked without a
     subprocess device farm)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.compat import make_mesh
 
     L, k = 96, 8
     codec = comm.get_codec(cname)

@@ -268,12 +268,12 @@ def test_spa_disabled_controller_is_bit_for_bit(kind):
         with mesh:
             if adaptive is None:
                 for _ in range(4):
-                    agg, state = step(grads, state)
+                    agg, state, _ = step(grads, state)
                     aggs.append(np.asarray(agg["w"]))
             else:
-                ctrl, _ = init_controller_state(plan, dist)
+                ctrl, _ = init_controller_state(plan, dist, mesh)
                 for _ in range(4):
-                    agg, state, ctrl = step(grads, state, ctrl)
+                    agg, state, ctrl, _ = step(grads, state, ctrl)
                     aggs.append(np.asarray(agg["w"]))
         return aggs, state
 
@@ -340,7 +340,7 @@ def test_adaptive_spa_round_compiles_once():
     )
     plan = {"w": LeafPlan((J,), (J,), J, 32, P(None), fused=False)}
     state, specs = init_sparsifier_state(plan, 1, mesh, ("data",), jnp.float32)
-    ctrl, _ = init_controller_state(plan, dist)
+    ctrl, _ = init_controller_state(plan, dist, mesh)
     spa = make_sparsify_aggregate(mesh, plan, {"w": P(None)}, specs, dist, 1)
     counted, calls = _counting(spa)
     step = jax.jit(counted)
@@ -348,7 +348,7 @@ def test_adaptive_spa_round_compiles_once():
     ks = []
     with mesh:
         for _ in range(6):
-            agg, state, ctrl = step(grads, state, ctrl)
+            agg, state, ctrl, _ = step(grads, state, ctrl)
             ks.append(int(ctrl["w"].k))
     jax.block_until_ready(agg)
     assert calls["n"] == 1, f"adaptive shard_map retraced: {calls['n']}"

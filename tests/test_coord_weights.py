@@ -108,9 +108,10 @@ def test_shard_coord_matches_reference_single_device(cname, sname):
     """shard_coord == reference_coord on an in-process 1-device mesh
     (the 8-device subprocess bit-for-bit check lives in
     tests/test_distributed.py)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.compat import make_mesh
 
     L, k = 96, 8
     codec = comm.get_codec(cname)

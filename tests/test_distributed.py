@@ -481,7 +481,7 @@ def test_spa_participation_round_loop_compiles_once_multidevice():
         grads = {"w": jnp.linspace(-1.0, 1.0, 4 * 256).reshape(4, 256)}
         with mesh:
             for _ in range(5):
-                agg, state = step(grads, state)
+                agg, state, _ = step(grads, state)
         jax.block_until_ready(agg)
         print(json.dumps({"traces": calls["n"],
                           "t": int(state["w"].t[0])}))
@@ -498,7 +498,8 @@ COORD_SUB = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
     from repro import comm
 
     W, L, k = 8, 96, 9

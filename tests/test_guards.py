@@ -14,10 +14,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import comm
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 from repro.core.simulator import DistributedSim
 from repro.core.sparsify import SparsifierConfig
 
@@ -138,7 +139,7 @@ def test_make_sparsify_aggregate_round_loop_compiles_once():
     grads = {"w": jnp.linspace(-1.0, 1.0, 256).reshape(1, 256)}
     with mesh:
         for _ in range(4):
-            agg, state = step(grads, state)
+            agg, state, _ = step(grads, state)
     jax.block_until_ready(agg)
     assert calls["n"] == 1, (
         f"make_sparsify_aggregate retraced: {calls['n']} traces in 4 rounds"
@@ -213,7 +214,10 @@ def _route_recorder(monkeypatch):
     def fake_fused(scfg, st, g, k, *, interpret=None):
         hits["n"] += 1
         a = st.eps + g.astype(st.eps.dtype)
-        return a, jnp.zeros((k,), a.dtype), jnp.zeros((k,), jnp.int32)
+        return (
+            a, jnp.zeros((k,), a.dtype), jnp.zeros((k,), jnp.int32),
+            jnp.bool_(False),
+        )
 
     monkeypatch.setattr(fp, "fused_compact_select", fake_fused)
     return hits
