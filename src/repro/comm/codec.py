@@ -226,8 +226,10 @@ class CooQ8(Codec):
     supports_fused = True
 
     def encode(self, vals, idx, length):
-        amax = jnp.max(jnp.abs(vals))
-        scale = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
+        # the scale in float32: rounded in a bfloat16 payload's own dtype it
+        # can fall below amax / 127 and clip the largest value
+        amax = jnp.max(jnp.abs(vals)).astype(jnp.float32)
+        scale = jnp.where(amax > 0, amax / 127.0, 1.0)
         q = jnp.clip(jnp.round(vals / scale), -127, 127).astype(jnp.int8)
         return {"q": q, "scale": scale, "idx": idx.astype(jnp.int32)}
 

@@ -35,9 +35,10 @@ is independent), so ``overlap="off"`` and any bucket count are bit-for-bit
 identical — asserted across codecs in ``tests/test_overlap.py`` and on a
 real 8-device mesh in ``tests/test_distributed.py``. What changes is the
 *schedule* the planner predicts (``CommPlan.buckets`` /
-``CommPlan.timeline``) and the profiler-visible structure of the round
-(each bucket runs under a ``jax.named_scope`` annotation, surfaced as
-``metrics["timeline"]`` stamps by ``make_train_step``).
+``CommPlan.timeline``, and ``comm_round_timeline`` for the live runtime)
+and the profiler-visible structure of the round: each bucket runs under a
+``jax.named_scope("spa_bucketNNN")`` annotation, around the round's stage
+scopes (``repro.core.stages``).
 """
 from __future__ import annotations
 

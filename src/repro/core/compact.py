@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import selectors as sel_lib
+from repro.core import stages
 from repro.core.sparsify import SparsifierConfig
 
 
@@ -97,6 +98,11 @@ def compact_select(
     ``None``/``"off"`` is the historical dense selection. ``"auto"``
     additionally requires a TPU backend and the throughput table's
     blessing, mirroring ``DistConfig.resolved_fastpath``.
+
+    The score is traced under the ``spa.score`` scope, top-k and the
+    payload's gather under ``spa.select`` (:mod:`repro.core.stages`); the
+    fused pipeline scores and selects in one kernel, so all of it is
+    ``spa.select``.
     """
     L = g.shape[0]
     if k_dyn is not None and (
@@ -127,61 +133,66 @@ def compact_select(
                 )
             )
         ):
-            a, vals, idx, _ = fp.fused_compact_select(cfg, st, g, k)
-            if k_dyn is None:
-                return a, vals, idx
-            return apply_k_dyn(a, vals, idx, k_dyn, k)
-    a = st.eps + g.astype(st.eps.dtype)
+            with jax.named_scope(stages.SELECT):
+                a, vals, idx, _ = fp.fused_compact_select(cfg, st, g, k)
+                if k_dyn is None:
+                    return a, vals, idx
+                return apply_k_dyn(a, vals, idx, k_dyn, k)
+    with jax.named_scope(stages.SCORE):
+        a = st.eps + g.astype(st.eps.dtype)
     if cfg.kind == "none":
         raise ValueError("'none' bypasses compact_select")
     if cfg.kind == "cyclic":
         # Beyond-paper coordinated round-robin (common across workers):
         # the mask is a pure function of (t, k, L) -> exact cancellation of
         # heterogeneous components (see EXPERIMENTS.md §Beyond).
-        start = (st.t * k) % L
-        idx = (start + jnp.arange(k)) % L
-        return a, a[idx], idx
+        with jax.named_scope(stages.SELECT):
+            start = (st.t * k) % L
+            idx = (start + jnp.arange(k)) % L
+            return a, a[idx], idx
 
-    amag = jnp.abs(a)
-    if cfg.kind == "topk":
-        score = amag
-    elif cfg.kind == "regtopk":
-        # Remark-4 prior exponent: the selection metric is |a|^y * reg. The
-        # exponent must be applied *before* the sent-coordinate
-        # regularization so sent scores are mag^y * reg, matching
-        # RegTopK._score (t == 0 is plain Top-k — Alg. 2 line 2).
-        mag = amag if cfg.y == 1.0 else amag**cfg.y
-        # dense default: unsent coords carry likelihood C = tanh(Q/mu) -> 1.
-        # Under coordinate weighting the server divided each sent coord by
-        # its sender mass (sent_w), so this worker's effective omega there
-        # was omega / sent_w; worker weighting records sent_w == 1, making
-        # the division exact and the path bit-for-bit with the scalar form.
-        w_safe = jnp.where(st.sent_w > 0, st.sent_w, 1.0)
-        omega_vec = cfg.omega / w_safe
-        denom = omega_vec * a[st.sent_idx]
-        safe = jnp.where(denom == 0, 1.0, denom)
-        delta = (st.sent_g - omega_vec * st.sent_vals) / safe
-        reg = jnp.tanh(jnp.abs(1.0 + delta) / cfg.mu)
-        sent_score = mag[st.sent_idx] * reg
-        score = jnp.where(
-            st.t == 0, amag, mag.at[st.sent_idx].set(sent_score)
-        )
-    else:
-        raise ValueError(f"unsupported compact kind {cfg.kind!r}")
-    if cfg.selector == "exact":
-        _, idx = jax.lax.top_k(score, k)
-        # zero scores are never selected (parity with exact_topk_mask):
-        # unfilled slots keep their (distinct) top-k index but carry value
-        # 0 — a no-op contribution on the wire, and no duplicate indices
-        # for the scatter consumers downstream.
-        vals = a[idx] * (score[idx] > 0)
-        if k_dyn is None:
+    with jax.named_scope(stages.SCORE):
+        amag = jnp.abs(a)
+        if cfg.kind == "topk":
+            score = amag
+        elif cfg.kind == "regtopk":
+            # Remark-4 prior exponent: the selection metric is |a|^y * reg. The
+            # exponent must be applied *before* the sent-coordinate
+            # regularization so sent scores are mag^y * reg, matching
+            # RegTopK._score (t == 0 is plain Top-k — Alg. 2 line 2).
+            mag = amag if cfg.y == 1.0 else amag**cfg.y
+            # dense default: unsent coords carry likelihood C = tanh(Q/mu) -> 1.
+            # Under coordinate weighting the server divided each sent coord by
+            # its sender mass (sent_w), so this worker's effective omega there
+            # was omega / sent_w; worker weighting records sent_w == 1, making
+            # the division exact and the path bit-for-bit with the scalar form.
+            w_safe = jnp.where(st.sent_w > 0, st.sent_w, 1.0)
+            omega_vec = cfg.omega / w_safe
+            denom = omega_vec * a[st.sent_idx]
+            safe = jnp.where(denom == 0, 1.0, denom)
+            delta = (st.sent_g - omega_vec * st.sent_vals) / safe
+            reg = jnp.tanh(jnp.abs(1.0 + delta) / cfg.mu)
+            sent_score = mag[st.sent_idx] * reg
+            score = jnp.where(
+                st.t == 0, amag, mag.at[st.sent_idx].set(sent_score)
+            )
+        else:
+            raise ValueError(f"unsupported compact kind {cfg.kind!r}")
+    with jax.named_scope(stages.SELECT):
+        if cfg.selector == "exact":
+            _, idx = jax.lax.top_k(score, k)
+            # zero scores are never selected (parity with exact_topk_mask):
+            # unfilled slots keep their (distinct) top-k index but carry value
+            # 0 — a no-op contribution on the wire, and no duplicate indices
+            # for the scatter consumers downstream.
+            vals = a[idx] * (score[idx] > 0)
+            if k_dyn is None:
+                return a, vals, idx
+            return apply_k_dyn(a, vals, idx, k_dyn, k)
+        if cfg.selector == "threshold":
+            mask = sel_lib.threshold_topk_mask(score, k)
+            vals, idx = sel_lib.mask_to_payload(mask, a, k)
             return a, vals, idx
-        return apply_k_dyn(a, vals, idx, k_dyn, k)
-    if cfg.selector == "threshold":
-        mask = sel_lib.threshold_topk_mask(score, k)
-        vals, idx = sel_lib.mask_to_payload(mask, a, k)
-        return a, vals, idx
     raise ValueError(
         f"compact_select does not support selector {cfg.selector!r}; "
         "available: 'exact', 'threshold'"

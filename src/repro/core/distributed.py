@@ -40,6 +40,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import comm
 from repro.core import compact as C
+from repro.core import stages
 from repro.core.selectors import sparsity_to_k
 from repro.core.sparsify import SparsifierConfig
 from repro.models.config import ModelConfig
@@ -109,8 +110,9 @@ class DistConfig:
     # pipelines behind the next bucket's intra-axis work. Numerics are
     # untouched (bucketing only reorders independent per-leaf rounds):
     # "off" and any B are bit-for-bit identical; what changes is the
-    # predicted round timeline (comm_round_timeline, metrics["timeline"])
-    # and the profiler annotation structure (jax.named_scope per bucket).
+    # predicted round timeline (comm_round_timeline) and the profiler
+    # annotation structure (a jax.named_scope per bucket around the
+    # round's stage scopes).
     overlap: str = "off"
 
     def resolved_collective(self) -> str:
@@ -494,7 +496,10 @@ def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
     strategy psums the sparse-but-dense vector (uncompressed, exact); payload
     strategies encode the fixed-k payload with ``codec``, run the collective,
     and error-feed back against the *decoded* contribution so lossy codecs
-    (``coo_q8``) keep their residual in ``eps``.
+    (``coo_q8``) keep their residual in ``eps``. Each stage is traced under
+    its scope of :mod:`repro.core.stages`: ``spa.score`` and ``spa.select``
+    (inside ``compact_select``; a fused leaf is all ``spa.select``),
+    ``spa.encode``, ``spa.exchange`` and ``spa.feedback``.
 
     ``fused`` routes selection through the Pallas fused select→encode
     pipeline (``comm.fastpath.fused_compact_select`` +
@@ -535,24 +540,26 @@ def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
         m, w_part = part_ctx
     fell_back = jnp.zeros((), jnp.float32)
     if scfg.kind == "none":
-        if part_ctx is None:
-            agg = jax.lax.pmean(
-                gl.astype(jnp.float32), dp_axes
-            ).astype(gl.dtype)
-        else:
-            # no error state: a dropped worker's gradient is simply lost
-            agg = jax.lax.psum(
-                gl.astype(jnp.float32) * (m * w_part), dp_axes
-            ).astype(gl.dtype)
+        with jax.named_scope(stages.EXCHANGE):
+            if part_ctx is None:
+                agg = jax.lax.pmean(
+                    gl.astype(jnp.float32), dp_axes
+                ).astype(gl.dtype)
+            else:
+                # no error state: a dropped worker's gradient is simply lost
+                agg = jax.lax.psum(
+                    gl.astype(jnp.float32) * (m * w_part), dp_axes
+                ).astype(gl.dtype)
         new = stl._replace(t=stl.t + 1)
     else:
         if fused:
-            a, vals, idx, fb = comm.fastpath.fused_compact_select(
-                scfg, stl, gl, p.k
-            )
-            fell_back = fb.astype(jnp.float32)
-            if k_dyn is not None:
-                a, vals, idx = C.apply_k_dyn(a, vals, idx, k_dyn, p.k)
+            with jax.named_scope(stages.SELECT):
+                a, vals, idx, fb = comm.fastpath.fused_compact_select(
+                    scfg, stl, gl, p.k
+                )
+                fell_back = fb.astype(jnp.float32)
+                if k_dyn is not None:
+                    a, vals, idx = C.apply_k_dyn(a, vals, idx, k_dyn, p.k)
         else:
             a, vals, idx = C.compact_select(scfg, stl, gl, p.k, k_dyn=k_dyn)
         omega = scfg.omega if part_ctx is None else w_part
@@ -560,59 +567,66 @@ def _spa_leaf(g, st, p: LeafPlan, scfg, codec, collective, dp_axes,
         coord = weighting == "coordinate"
         den = None  # per-coordinate sender mass (coordinate weighting)
         if collective == "dense_allreduce":
-            # scatter-ADD: payload padding (value 0 on a real or duplicate
-            # index) must be a no-op, never overwrite a live contribution
-            ghat = jnp.zeros_like(a).at[idx].add(vals)
-            w = omega if part_ctx is None else omega * m
-            if coord:
-                # presence from the dense contribution (mirrors
-                # DenseAllreduce.shard_coord): padding slots carry value 0
-                # and contribute no sender mass.
-                presence = (ghat != 0).astype(ghat.dtype)
-                num = jax.lax.psum(ghat * w, dp_axes)
-                den = jax.lax.psum(presence * w, dp_axes)
-                agg = num / jnp.maximum(den, jnp.finfo(den.dtype).tiny)
-            else:
-                agg = jax.lax.psum(ghat * w, dp_axes)
-            new = C.compact_finalize(stl, a, vals, idx, agg, den=den)
+            with jax.named_scope(stages.EXCHANGE):
+                # scatter-ADD: payload padding (value 0 on a real or duplicate
+                # index) must be a no-op, never overwrite a live contribution
+                ghat = jnp.zeros_like(a).at[idx].add(vals)
+                w = omega if part_ctx is None else omega * m
+                if coord:
+                    # presence from the dense contribution (mirrors
+                    # DenseAllreduce.shard_coord): padding slots carry value 0
+                    # and contribute no sender mass.
+                    presence = (ghat != 0).astype(ghat.dtype)
+                    num = jax.lax.psum(ghat * w, dp_axes)
+                    den = jax.lax.psum(presence * w, dp_axes)
+                    agg = num / jnp.maximum(den, jnp.finfo(den.dtype).tiny)
+                else:
+                    agg = jax.lax.psum(ghat * w, dp_axes)
+            with jax.named_scope(stages.FEEDBACK):
+                new = C.compact_finalize(stl, a, vals, idx, agg, den=den)
         else:
-            payload = (
-                codec.encode_fused(vals, idx, p.local_len)
-                if fused
-                else codec.encode(vals, idx, p.local_len)
-            )
-            dvals, didx = codec.decode(payload, p.local_len)
-            sent_dense = (
-                jnp.zeros_like(a).at[didx].add(dvals.astype(a.dtype))
-            )
-            strategy = comm.get_collective(collective)
-            if coord:
-                agg, den = strategy.shard_coord(
-                    codec, payload, p.local_len, dp_axes, omega,
-                    participation=shard_mask,
+            with jax.named_scope(stages.ENCODE):
+                payload = (
+                    codec.encode_fused(vals, idx, p.local_len)
+                    if fused
+                    else codec.encode(vals, idx, p.local_len)
                 )
-                agg = agg.astype(a.dtype)
-                den = den.astype(a.dtype)
-            else:
-                agg = strategy.shard(
-                    codec, payload, p.local_len, dp_axes, omega,
-                    participation=shard_mask,
-                ).astype(a.dtype)
-            new = C.compact_finalize_sent(
-                stl, a, dvals, didx, sent_dense, agg, den=den
-            )
+            with jax.named_scope(stages.FEEDBACK):
+                dvals, didx = codec.decode(payload, p.local_len)
+                sent_dense = (
+                    jnp.zeros_like(a).at[didx].add(dvals.astype(a.dtype))
+                )
+            strategy = comm.get_collective(collective)
+            with jax.named_scope(stages.EXCHANGE):
+                if coord:
+                    agg, den = strategy.shard_coord(
+                        codec, payload, p.local_len, dp_axes, omega,
+                        participation=shard_mask,
+                    )
+                    agg = agg.astype(a.dtype)
+                    den = den.astype(a.dtype)
+                else:
+                    agg = strategy.shard(
+                        codec, payload, p.local_len, dp_axes, omega,
+                        participation=shard_mask,
+                    ).astype(a.dtype)
+            with jax.named_scope(stages.FEEDBACK):
+                new = C.compact_finalize_sent(
+                    stl, a, dvals, didx, sent_dense, agg, den=den
+                )
         if part_ctx is not None:
-            dropped = C.CompactState(
-                eps=a,
-                sent_vals=stl.sent_vals,
-                sent_g=stl.sent_g,
-                sent_idx=stl.sent_idx,
-                sent_w=stl.sent_w,
-                t=stl.t + 1,
-            )
-            new = jax.tree.map(
-                lambda live, gone: jnp.where(m > 0, live, gone), new, dropped
-            )
+            with jax.named_scope(stages.FEEDBACK):
+                dropped = C.CompactState(
+                    eps=a,
+                    sent_vals=stl.sent_vals,
+                    sent_g=stl.sent_g,
+                    sent_idx=stl.sent_idx,
+                    sent_w=stl.sent_w,
+                    t=stl.t + 1,
+                )
+                new = jax.tree.map(
+                    lambda live, gone: jnp.where(m > 0, live, gone), new, dropped
+                )
     new_out = C.CompactState(
         eps=new.eps.reshape((1,) + p.local_shape),
         sent_vals=new.sent_vals[None, None],
@@ -932,7 +946,13 @@ def make_train_step(
     (params, opt_state, sp_state, metrics)
 
     ``metrics["fastpath_fallbacks"]`` counts the fused-leaf selections
-    (leaf x device) that fell back to dense selection this step.
+    (leaf x device) that fell back to dense selection this step. The
+    step's stages run under the named scopes of :mod:`repro.core.stages`
+    (``train.grads``, ``train.round``, ``train.optimizer``, and the
+    round's ``spa.*``), so a profiler trace can be split by stage. The
+    wire bytes and the overlap schedule are compile-time constants: the
+    host has them from :func:`comm_round_bytes` and
+    :func:`comm_round_timeline`.
 
     With ``dist.adaptive_k`` set, ``sp_state`` is the *pair*
     ``(compact_state_tree, controller_state_tree)`` (see
@@ -948,23 +968,6 @@ def make_train_step(
     dp_spec = (
         tuple(dist.dp_axes) if len(dist.dp_axes) > 1 else dist.dp_axes[0]
     )
-    wire_pred, wire_meas = comm_round_bytes(plan, dist, mesh)
-    # bucketed overlap instrumentation: the per-bucket (launch, complete)
-    # stamps of the predicted round timeline, surfaced every step as
-    # metrics["timeline"] [n_buckets, 2] alongside the jax.named_scope
-    # annotations the aggregation emits per bucket (profiler-visible —
-    # jax.profiler traces group the collectives under spa_bucketNNN).
-    timeline_stamps = None
-    if dist.resolved_overlap() is not None:
-        _, tl = comm_round_timeline(plan, dist, mesh)
-        timeline_stamps = np.stack(
-            [
-                np.asarray(tl.launch, np.float32),
-                np.asarray(tl.complete, np.float32),
-            ],
-            axis=1,
-        )
-
     acc_dt = _DT[dist.state_dtype]
 
     def worker_grads(params, wbatch):
@@ -997,34 +1000,32 @@ def make_train_step(
         return loss, grads
 
     def train_step(params, opt_state, sp_state, batch):
-        wb = jax.tree.map(
-            lambda x: jax.lax.with_sharding_constraint(
-                x.reshape((W, x.shape[0] // W) + x.shape[1:]),
-                NamedSharding(mesh, P(dp_spec)),
-            ),
-            batch,
-        )
-        losses, grads_w = jax.vmap(worker_grads, in_axes=(None, 0))(params, wb)
-        grads_w = jax.tree.map(
-            lambda g: g.astype(_DT[dist.state_dtype]), grads_w
-        )
-        if adaptive:
-            cp_state, ctrl_state = sp_state
-            agg, new_cp, new_ctrl, fallbacks = spa(
-                grads_w, cp_state, ctrl_state
+        with jax.named_scope(stages.GRADS):
+            wb = jax.tree.map(
+                lambda x: jax.lax.with_sharding_constraint(
+                    x.reshape((W, x.shape[0] // W) + x.shape[1:]),
+                    NamedSharding(mesh, P(dp_spec)),
+                ),
+                batch,
             )
-            new_sp = (new_cp, new_ctrl)
-        else:
-            agg, new_sp, fallbacks = spa(grads_w, sp_state)
-        new_params, new_opt = opt.update(agg, opt_state, params)
-        metrics = {
-            "loss": losses.mean(),
-            "fastpath_fallbacks": fallbacks,
-            "comm_bytes": jnp.asarray(wire_meas, jnp.float32),
-            "comm_bytes_predicted": jnp.asarray(wire_pred, jnp.float32),
-        }
-        if timeline_stamps is not None:
-            metrics["timeline"] = jnp.asarray(timeline_stamps)
+            losses, grads_w = jax.vmap(worker_grads, in_axes=(None, 0))(
+                params, wb
+            )
+            grads_w = jax.tree.map(
+                lambda g: g.astype(_DT[dist.state_dtype]), grads_w
+            )
+        with jax.named_scope(stages.ROUND):
+            if adaptive:
+                cp_state, ctrl_state = sp_state
+                agg, new_cp, new_ctrl, fallbacks = spa(
+                    grads_w, cp_state, ctrl_state
+                )
+                new_sp = (new_cp, new_ctrl)
+            else:
+                agg, new_sp, fallbacks = spa(grads_w, sp_state)
+        with jax.named_scope(stages.OPTIMIZER):
+            new_params, new_opt = opt.update(agg, opt_state, params)
+        metrics = {"loss": losses.mean(), "fastpath_fallbacks": fallbacks}
         if adaptive:
             # the k each leaf *used* this round (ctrl carries next round's)
             ks = [

@@ -195,7 +195,10 @@ def main(argv=None) -> TrainRun:
                          "buckets so hierarchical's slow inter-axis stage "
                          "pipelines behind the next bucket's intra-axis "
                          "work; numerics are bit-for-bit identical either "
-                         "way (metrics gain per-bucket 'timeline' stamps)")
+                         "way (each bucket's round runs under a "
+                         "spa_bucketNNN profiler scope around its stage "
+                         "scopes; the predicted schedule is printed at "
+                         "start-up)")
     ap.add_argument("--replan-every", type=int, default=0, metavar="N",
                     help="every N steps, re-fit the alpha-beta link model "
                          "from live collective probes and re-plan the "

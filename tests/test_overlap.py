@@ -227,7 +227,7 @@ def test_plan_tree_overlap_schedule():
 
 
 # ---------------------------------------------------------------------------
-# distributed runtime: off-switch bit-for-bit + timeline metric
+# distributed runtime: off-switch bit-for-bit + the round timeline
 # ---------------------------------------------------------------------------
 
 
@@ -264,10 +264,10 @@ def _micro_train(overlap, codec, steps=2, monkey_costs=None, monkeypatch=None):
     step = jax.jit(asm.train_step)
     with mesh:
         for t in range(steps):
-            params, opt_state, sp_state, m = step(
+            params, opt_state, sp_state, _ = step(
                 params, opt_state, sp_state, pipe.batch_at(t)
             )
-    return params, m
+    return params, (asm.plan, dist, mesh)
 
 
 def _synthetic_costs(plan, dist, mesh):
@@ -287,15 +287,17 @@ def _synthetic_costs(plan, dist, mesh):
 
 @pytest.mark.parametrize("codec", ["coo_fp32", "coo_idx_delta", "coo_q8"])
 def test_bucketed_aggregation_bitforbit(codec, monkeypatch):
-    p_off, m_off = _micro_train("off", codec)
-    p_on, m_on = _micro_train(
+    from repro.core import distributed as D
+
+    p_off, _ = _micro_train("off", codec)
+    p_on, on = _micro_train(
         "buckets:3", codec,
         monkey_costs=_synthetic_costs, monkeypatch=monkeypatch,
     )
     for a, b in zip(jax.tree.leaves(p_off), jax.tree.leaves(p_on)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert "timeline" not in m_off
-    tl = np.asarray(m_on["timeline"])
+    _, timeline = D.comm_round_timeline(*on)
+    tl = np.stack([timeline.launch, timeline.complete], axis=1)
     assert tl.shape == (3, 2)
     # launch <= complete per bucket, completes monotone
     assert (tl[:, 0] <= tl[:, 1]).all()
