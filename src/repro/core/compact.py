@@ -21,6 +21,7 @@ one (worker × model-shard) flat vector of length L.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import NamedTuple, Tuple
 
 import jax
@@ -67,6 +68,67 @@ def apply_k_dyn(a, vals, idx, k_dyn, capacity: int):
     static path uses for unfilled slots."""
     keep = (jnp.arange(capacity) < k_dyn).astype(vals.dtype)
     return a, vals * keep, idx
+
+
+# Scores shorter than this select with one ``lax.top_k``. On the v5e the
+# two-level path was faster at every length swept, 2^16 to 2^28 at S = 0.01,
+# but each two-level leaf adds two sorts to the step, ~2.5 MB of device code
+# each; from 2^20 up it saves 0.9 ms a call or more (the sweep in PERF.md).
+TWO_LEVEL_MIN_LEN = 1 << 20
+_LANES = 128  # a TPU vector row; groups are lane segments of one row
+
+
+def top_k_group(length: int, k: int) -> int:
+    """Group size G with which :func:`exact_top_k` selects ``k`` of a
+    ``length``-element score: 1 is one ``lax.top_k`` over the whole score,
+    G > 1 the two-level path over groups of G. G is the power of two
+    nearest ``sqrt(length / k)`` (8 at S = 0.01), at most 128, taken only
+    from ``TWO_LEVEL_MIN_LEN`` up and where the ``k·G`` candidates are at
+    most half the score."""
+    if length < TWO_LEVEL_MIN_LEN or k < 1:
+        return 1
+    g = 1 << max(0, math.floor(0.5 * math.log2(length / k) + 0.5))
+    g = min(g, _LANES)
+    return g if 2 * k * g <= length else 1
+
+
+def exact_top_k(score: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """``jax.lax.top_k(score, k)`` bit for bit (values, indices, order) for
+    a flat score ``>= 0``, without sorting the whole score.
+
+    Two levels over contiguous groups of G (:func:`top_k_group`): the top
+    k of the group maxima (one ``lax.top_k`` over L/G elements), the k
+    winning groups in ascending order, then the k·G scores of those groups,
+    which lie in index order, sorted descending by a stable sort, as
+    ``lax.top_k`` sorts. Exact, ties included: if x were in the top k but
+    its group lost, each of the k groups ranked above it holds an element
+    ahead of x (a larger score, or an equal one in a group of lower ids,
+    so at a lower index): k elements ahead of x.
+    """
+    length = score.shape[0]
+    g = top_k_group(length, k)
+    if g == 1:
+        return jax.lax.top_k(score, k)
+    rows, per_row = -(-length // _LANES), _LANES // g
+    # whole rows of 128 lanes, padded with -1, which every score beats: no
+    # group wholly of padding wins, and no padding is among the k selected
+    s = jnp.pad(score, (0, rows * _LANES - length), constant_values=-1)
+    s = s.reshape(rows, _LANES)
+    # group maxima in index order; through the transpose the lane segments
+    # become sublanes, which the TPU reduces without a relayout
+    gmax = s.T.reshape(per_row, g, rows).max(axis=1).T.reshape(-1)
+    _, gid = jax.lax.top_k(gmax, k)
+    gid = jnp.sort(gid)
+    # each winning group's row (a row gather, not k·G element gathers),
+    # then its segment of g lanes: the candidates in index order
+    row, col = jax.lax.div(gid, per_row), jax.lax.rem(gid, per_row)
+    seg = jnp.arange(per_row) == col[:, None]
+    cand = s[row].reshape(k, per_row, g)
+    cand = jnp.where(seg[..., None], cand, -1).max(axis=1).reshape(-1)
+    idx = (gid[:, None] * g + jnp.arange(g, dtype=gid.dtype)).reshape(-1)
+    # sorted with the indices beside them: no gather of positions after
+    neg, idx = jax.lax.sort((-cand, idx), num_keys=1, is_stable=True)
+    return -neg[:k], idx[:k]
 
 
 def compact_select(
@@ -180,12 +242,12 @@ def compact_select(
             raise ValueError(f"unsupported compact kind {cfg.kind!r}")
     with jax.named_scope(stages.SELECT):
         if cfg.selector == "exact":
-            _, idx = jax.lax.top_k(score, k)
+            top, idx = exact_top_k(score, k)
             # zero scores are never selected (parity with exact_topk_mask):
             # unfilled slots keep their (distinct) top-k index but carry value
             # 0 — a no-op contribution on the wire, and no duplicate indices
             # for the scatter consumers downstream.
-            vals = a[idx] * (score[idx] > 0)
+            vals = a[idx] * (top > 0)
             if k_dyn is None:
                 return a, vals, idx
             return apply_k_dyn(a, vals, idx, k_dyn, k)

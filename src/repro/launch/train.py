@@ -433,6 +433,21 @@ def main(argv=None) -> TrainRun:
             f"comm:   fastpath: {n_fused}/{len(leaves)} leaves fused",
             flush=True,
         )
+    sp = dist.sparsifier
+    if sp.kind in ("topk", "regtopk") and sp.selector == "exact":
+        from repro.core.compact import top_k_group
+
+        two = [
+            p
+            for p in leaves
+            if not leaf_fastpath(p, dist) and top_k_group(p.local_len, p.k) > 1
+        ]
+        share = sum(p.local_len for p in two) / sum(p.local_len for p in leaves)
+        print(
+            f"select: two-level top-k on {len(two)}/{len(leaves)} leaves "
+            f"({share:.1%} of the gradient's elements)",
+            flush=True,
+        )
     plan = asm.plan
     losses, fallbacks = [], []
     with mesh:

@@ -6,6 +6,7 @@ process keeps seeing 1 device (per the dry-run isolation contract).
 """
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -428,6 +429,11 @@ def test_train_cli_checkpoint_resume(tmp_path):
                         capture_output=True, text=True, env=env, timeout=480)
     assert r1.returncode == 0, r1.stderr[-2000:]
     assert "checkpointed" in r1.stdout
+    assert re.search(
+        r"select: two-level top-k on \d+/\d+ leaves \(\d+\.\d% of the "
+        r"gradient's elements\)",
+        r1.stdout,
+    )
     r2 = subprocess.run([*base, "--resume", ckpt],
                         capture_output=True, text=True, env=env, timeout=480)
     assert r2.returncode == 0, r2.stderr[-2000:]

@@ -19,6 +19,7 @@ from repro.core import (
     sparsity_to_k,
     threshold_topk_mask,
 )
+from repro.core import compact
 
 jax.config.update("jax_enable_x64", False)
 
@@ -126,6 +127,74 @@ def test_threshold_matches_exact_when_distinct():
     m_t = threshold_topk_mask(score, 2, n_iters=40)
     m_e = exact_topk_mask(score, 2)
     np.testing.assert_array_equal(m_t, m_e)
+
+
+@pytest.mark.parametrize(
+    "case,L,k,G",
+    [
+        ("random", 4096, 37, 8),
+        ("ties", 4096, 37, 8),
+        ("mostly_zero", 4096, 200, 4),  # 122 positive scores, k = 200
+        ("zeros", 4096, 37, 8),
+        ("random", 999, 10, 8),  # 999 = 124·8 + 7 = 7·128 + 103
+        ("ties", 1200, 300, 2),  # k·G = L/2 exactly
+        ("ties", 1199, 300, 1),  # k·G just over L/2: one lax.top_k
+        ("random", 30000, 3, 128),  # a group is a whole row of lanes
+    ],
+)
+def test_exact_top_k_equals_lax_top_k(monkeypatch, case, L, k, G):
+    """The two-level top-k returns lax.top_k's values and indices, in its
+    order, bit for bit: ties go to the lower index, zero scores fill the
+    slots past the positives as lax.top_k fills them."""
+    monkeypatch.setattr(compact, "TWO_LEVEL_MIN_LEN", 16)
+    assert compact.top_k_group(L, k) == G
+    rng = np.random.default_rng(L + k)
+    x = rng.random(L, dtype=np.float32)
+    if case == "ties":
+        x = np.round(x * 4) / 4
+    elif case == "mostly_zero":
+        x[rng.permutation(L)[int(0.03 * L):]] = 0
+    elif case == "zeros":
+        x[:] = 0
+    x = jnp.asarray(x)
+    want = jax.lax.top_k(x, k)
+    got = jax.jit(compact.exact_top_k, static_argnums=1)(x, k)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("dynamic", [False, True])
+def test_compact_select_two_level_leaf_matches_lax_top_k(monkeypatch, dynamic):
+    """On a leaf long enough for the two-level path, RegTop-k after round
+    0 (posterior-scored coordinates), with and without the adaptive k,
+    selects as one lax.top_k over the leaf does, and as the dense-state
+    oracle does."""
+    L = compact.TWO_LEVEL_MIN_LEN + 5
+    k = sparsity_to_k(L, 0.01)
+    assert compact.top_k_group(L, k) > 1
+    cfg = SparsifierConfig(kind="regtopk", sparsity=k / L, mu=1.5, omega=0.1)
+    k_dyn = jnp.int32(k // 2) if dynamic else None
+    g0, g1 = jax.random.normal(jax.random.PRNGKey(0), (2, L))
+    st = compact.compact_init(L, k)
+    a, vals, idx = compact.compact_select(cfg, st, g0, k)
+    agg = 0.1 * jnp.zeros(L).at[idx].add(vals)
+    st = compact.compact_finalize(st, a, vals, idx, agg)
+
+    def select(st, g):
+        return compact.compact_select(cfg, st, g, k, k_dyn=k_dyn)
+
+    got = jax.jit(select)(st, g1)
+    monkeypatch.setattr(compact, "TWO_LEVEL_MIN_LEN", L + 1)
+    want = jax.jit(lambda st, g: select(st, g))(st, g1)
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    ghat_ref, _, _ = compact.reference_step(
+        cfg, st, g1, agg, k // 2 if dynamic else k
+    )
+    ghat = jnp.zeros(L).at[got[2]].add(got[1])
+    np.testing.assert_allclose(
+        np.asarray(ghat), np.asarray(ghat_ref), rtol=1e-5, atol=1e-6
+    )
 
 
 def test_fixed_k_payload_roundtrip():

@@ -82,3 +82,29 @@ def test_stage_scopes_reach_compiled_hlo(devices, overlap):
     assert "optimizer" in {layers[n][0] for n in layers}
     if devices > 1:
         assert "all_gather" in by_stage[stages.EXCHANGE]
+
+
+def test_two_level_top_k_stays_in_select():
+    """With leaves long enough for the two-level top-k (the threshold is
+    lowered in the subprocess to fit the tiny model), its group maxima,
+    sorts and candidate gather sit under ``spa.select``, in the round
+    layer that ``round_ms`` reads."""
+    code = SUB_CODE.replace("{OVERLAP!r}", repr("off")).replace(
+        "import json\n",
+        "import json\nfrom repro.core import compact\n"
+        "compact.TWO_LEVEL_MIN_LEN = 64\n",
+        1,
+    )
+    hlo = run_sub(code, devices=1)["hlo"]
+    layers = trace.hlo_layers(hlo)
+    select_labels = set()
+    for name, op_name in _OP_NAME.findall(hlo):
+        layer, label = layers.get(name, ("other", ""))
+        stage = bench_stages.stage_of(op_name)
+        if label in ("sort", "top_k") and op_name != "sort":
+            # (a bare "sort" names a comparator's parameter)
+            assert stage == stages.SELECT, op_name
+        if stage == stages.SELECT:
+            assert layer == "round", op_name
+            select_labels.add(label)
+    assert {"top_k", "sort", "gather", "reduce_max", "transpose"} <= select_labels

@@ -84,3 +84,33 @@ def test_kernel_compiles_for_v5e(one_chip, name, leaf):
     x = jax.ShapeDtypeStruct((rows, ops.LANES), jnp.float32, sharding=one_chip)
     hlo = jax.jit(_kernel(name, length)).lower(x).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_exact_selection_sorts_no_whole_leaf(one_chip):
+    """RegTop-k's exact selection on a leaf long enough for the two-level
+    top-k compiles for the v5e with no sort over more than a quarter of
+    the leaf (one ``lax.top_k`` compiles to a stable sort of the whole
+    leaf)."""
+    import re
+
+    from repro.core import compact
+    from repro.core.sparsify import SparsifierConfig
+
+    length = max(compact.TWO_LEVEL_MIN_LEN, 1 << 22)
+    k = sparsity_to_k(length, SPARSITY)
+    cfg = SparsifierConfig(kind="regtopk", sparsity=SPARSITY, mu=1.0)
+    st = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: compact.compact_init(length, k)),
+    )
+    g = jax.ShapeDtypeStruct((length,), jnp.float32, sharding=one_chip)
+    hlo = (
+        jax.jit(lambda st, g: compact.compact_select(cfg, st, g, k))
+        .lower(st, g)
+        .compile()
+        .as_text()
+    )
+    sorts = re.findall(r"= (.*?) sort\(", hlo)
+    assert sorts
+    longest = max(int(n) for s in sorts for n in re.findall(r"\[(\d+)\]", s))
+    assert longest <= length // 4, longest
