@@ -49,7 +49,7 @@ def main(argv=None) -> int:
     def ref(seed):
         if seed not in refs:
             refs[seed] = reference.run(cell.model, m, traffic, seed,
-                                       cell.chips, devices=devices)
+                                       cell.chips, device=devices[0])
         return refs[seed]
 
     def emit(kind, seed, readings):
@@ -76,7 +76,7 @@ def main(argv=None) -> int:
     for seed in args.control_seeds:
         emit("control", seed, reference.run(
             cell.model, m, traffic, seed, cell.chips, dtype=jnp.bfloat16,
-            devices=devices))
+            device=devices[0]))
     if args.fault_seeds:
         program_runs("half_batch", args.fault_seeds, faults.half_batch)
         if cell.chips > 1:

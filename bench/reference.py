@@ -96,31 +96,29 @@ def _flat_grad(model, m, params, rows, share):
 
 
 def run(model, m, traffic, seed: int, n_workers: int, steps: int = 3,
-        dtype=jnp.float32, devices=None) -> Readings:
-    """The reference's readings over the first ``steps`` steps. Worker w
-    computes on ``devices[w % len(devices)]`` (default: the first device);
-    the aggregate and Adam on the first."""
+        dtype=jnp.float32, device=None) -> Readings:
+    """The reference's readings over the first ``steps`` steps, every
+    worker, the aggregate and Adam on ``device`` (default: the first), so
+    that each of its functions compiles once, however many workers."""
     b, seq = traffic["batch_per_chip"], traffic["seq"]
     sp, opt = traffic["sparsifier"], traffic["optimizer"]
     kind, omega = sp["kind"], 1.0 / n_workers
     R = model.REF_ROWS
-    devices = devices or jax.devices()[:1]
-    dev = [devices[w % len(devices)] for w in range(n_workers)]
-    home = dev[0]
-    put = jax.device_put
+    home = device or jax.devices()[0]
+    put = functools.partial(jax.device_put, device=home)
     with jax.default_matmul_precision("highest"):
         init = jax.jit(lambda k: model.init(k, m, jnp.float32))
         params = jax.tree.map(lambda x: x.astype(dtype),
-                              put(init(feed.weights_key(seed)), home))
+                              put(init(feed.weights_key(seed))))
         names = leaf_names(params)
         treedef = jax.tree.structure(params)
         shapes = [x.shape for x in jax.tree.leaves(params)]
         flat = [x.reshape(-1) for x in jax.tree.leaves(params)]
         ks = tuple(sparsity_to_k(x.size, sp["sparsity"]) for x in flat)
-        zeros = lambda d: put([jnp.zeros_like(x) for x in flat], d)
-        eps = [zeros(dev[w]) for w in range(n_workers)]
+        zeros = lambda: put([jnp.zeros_like(x) for x in flat])
+        eps = [zeros() for _ in range(n_workers)]
         prev = [(None, None, None)] * n_workers
-        adam_m, adam_v = zeros(home), zeros(home)
+        adam_m, adam_v = zeros(), zeros()
         make_batch = jax.jit(functools.partial(
             feed.make_batch, model=m, batch=b * n_workers, seq=seq))
         grad = jax.jit(functools.partial(_flat_grad, model, m))
@@ -135,18 +133,18 @@ def run(model, m, traffic, seed: int, n_workers: int, steps: int = 3,
             donate_argnums=(1, 2, 3))
         losses, grad_norms = [], None
         for t in range(steps):
-            batch = put(make_batch(feed.batches_key(seed), t), home)
+            batch = put(make_batch(feed.batches_key(seed), t))
             tree = jax.tree.unflatten(
                 treedef, [x.reshape(s) for x, s in zip(flat, shapes,
                                                        strict=True)])
             outs, step_loss = [], []
             for w in range(n_workers):  # dispatched without waiting
-                p_w, g_w, end = put(tree, dev[w]), None, (w + 1) * b
+                g_w, end = None, (w + 1) * b
                 for r in range(w * b, end, R):
-                    rows = put(jax.tree.map(
-                        lambda x, r=r: x[r:min(r + R, end)], batch), dev[w])
+                    rows = jax.tree.map(
+                        lambda x, r=r: x[r:min(r + R, end)], batch)
                     share = rows["tokens"].shape[0] / b
-                    lv, gv = grad(p_w, rows, share)
+                    lv, gv = grad(tree, rows, share)
                     step_loss.append(lv * (share / n_workers))
                     g_w = gv if g_w is None else add(g_w, gv)
                 if kind == "none":
@@ -158,14 +156,13 @@ def run(model, m, traffic, seed: int, n_workers: int, steps: int = 3,
                 prev[w] = (idx, vals)
             agg = None
             for o in outs:
-                o = [omega * x for x in put(o, home)]
+                o = [omega * x for x in o]
                 agg = o if agg is None else add(agg, o)
             if kind != "none":
                 for w in range(n_workers):
                     idx, vals = prev[w]
-                    prev[w] = (idx, vals, put(
-                        [a[i] for a, i in zip(agg, put(idx, home),
-                                              strict=True)], dev[w]))
+                    prev[w] = (idx, vals, [a[i] for a, i in
+                                           zip(agg, idx, strict=True)])
             del outs
             losses.append(sum(float(x) for x in step_loss))
             if t == 0:
@@ -174,7 +171,7 @@ def run(model, m, traffic, seed: int, n_workers: int, steps: int = 3,
                                         agg)
             del agg
         start = [x.reshape(-1) for x in jax.tree.leaves(
-            put(init(feed.weights_key(seed)), home))]
+            put(init(feed.weights_key(seed))))]
         delta = [x.astype(jnp.float32) - y for x, y in zip(flat, start,
                                                            strict=True)]
         return Readings(

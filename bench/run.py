@@ -30,6 +30,7 @@ import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Any, Dict, NamedTuple  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -50,13 +51,17 @@ TRACE_SECONDS = 6.0
 class LayerContext(NamedTuple):
     """What a per-layer metric reader is given."""
 
-    trace: trace_lib.Reduction
+    trace: trace_lib.Reduction  # device s by layer, by named scope, by op
     steps: int  # steps in the traced window
     window_s: float
     chips: int
     flops_per_step: float
-    peak_flops: float
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bytes_per_s: float  # per chip
     wire_bytes: int
+    model: ModuleType  # configs/<config>.py: reference, init, FLOPs
+    sizes: Dict[str, Any]  # the configuration's "model" sizes, as run
+    traffic: Dict[str, Any]  # traffic/<mix>.json
 
 
 def log(msg: str) -> None:
@@ -82,6 +87,20 @@ def peak_bytes(device) -> int:
     stats = device.memory_stats() or {}
     return (stats.get("peak_bytes_in_use", 0)
             + stats.get("peak_bytes_reserved", 0))
+
+
+_MEMORY_KEYS = ("bytes_in_use", "bytes_reserved", "largest_free_block_bytes",
+                "num_allocs", "largest_alloc_size")
+
+
+def memory_state(device) -> Dict[str, int]:
+    """The allocator's state, as far as the runtime reports it."""
+    stats = device.memory_stats() or {}
+    return {k: stats[k] for k in _MEMORY_KEYS if k in stats}
+
+
+def _ms(seconds) -> list:
+    return [round(1e3 * s, 3) for s in seconds]
 
 
 def use_compile_cache() -> None:
@@ -156,7 +175,11 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
         seconds = min(seconds, TRACE_SECONDS)
         jax.profiler.start_trace(tmp)
     step_losses = []
+    # host clock, from the window's start, when each step's feed and
+    # dispatch returned and when its loss was ready
+    fed, sent, done = [], [], []
     t = SETUP_STEPS
+    mem_before, gc_before = memory_state(devices[0]), gc.get_stats()
     setup_s = time.perf_counter() - t0
     w0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.window"):
@@ -165,11 +188,14 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
             with jax.profiler.StepTraceAnnotation("bench.step", step_num=t):
                 with jax.profiler.TraceAnnotation("bench.feed"):
                     batch = prog.batch(key, t)
+                fed.append(time.perf_counter() - w0)
                 with jax.profiler.TraceAnnotation("bench.dispatch"):
                     *state, metrics = step(*state, batch)
+                sent.append(time.perf_counter() - w0)
                 if pending is not None:
                     with jax.profiler.TraceAnnotation("bench.wait"):
                         pending.block_until_ready()
+                    done.append(time.perf_counter() - w0)
             pending = metrics["loss"]
             step_losses.append(pending)
             t += 1
@@ -178,13 +204,20 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
         with jax.profiler.TraceAnnotation("bench.wait"):
             jax.block_until_ready(state)
     window_s = time.perf_counter() - w0
+    done.append(window_s)
     n_steps = t - SETUP_STEPS
     peaks_in_use = [peak_bytes(d) for d in devices]
     peak = max(peaks_in_use)
     log(f"window {window_s:.4f} s, {n_steps} steps; peak bytes in use and "
         f"reserved per device {peaks_in_use}; device 0 memory_stats "
         f"{devices[0].memory_stats()}")
-    layers = trace_lib.hlo_layers(step.as_text()) if trace else None
+    log("window steps " + json.dumps({
+        "fed": _ms(fed), "sent": _ms(sent), "done": _ms(done),
+        "gc_collections": [a["collections"] - b["collections"] for a, b in
+                           zip(gc.get_stats(), gc_before, strict=True)],
+        "memory_before": mem_before,
+        "memory_after": memory_state(devices[0])}))
+    hlo = step.as_text() if trace else None
     failed = sum(not math.isfinite(float(x)) for x in step_losses)
     del state, metrics, pending, batch, step, step_losses
     gc.collect()
@@ -200,13 +233,16 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
         ops, spans = trace_lib.read(pb[0])
         shutil.rmtree(tmp, ignore_errors=True)
         red = trace_lib.reduce(ops, spans, W, trace_lib.window_of(spans),
-                               layers)
+                               trace_lib.hlo_layers(hlo),
+                               trace_lib.hlo_scopes(hlo))
+        chip = peaks.chip_peaks(dev.device_kind)
         ctx = LayerContext(
             trace=red, steps=n_steps, window_s=window_s, chips=W,
             flops_per_step=cell.model.flops_per_step(
                 m, global_batch, traffic["seq"]),
-            peak_flops=peaks.chip_peaks(dev.device_kind).bf16_flops,
-            wire_bytes=prog.wire_bytes)
+            peak_flops=chip.bf16_flops, hbm_bytes_per_s=chip.hbm_bytes_per_s,
+            wire_bytes=prog.wire_bytes, model=cell.model, sizes=m,
+            traffic=traffic)
         metrics_out = {}
         for spec in cell.per_layer:
             v = cell.readers[spec["name"]].read(ctx)
@@ -226,7 +262,7 @@ def run_cell(cell: cell_lib.Cell, seed: int, seconds: float, trace: bool,
                        for s in cell.end_to_end}
 
     ref = reference.run(cell.model, m, traffic, seed, W, SETUP_STEPS,
-                        devices=devices)
+                        device=dev)
     correct, checks = compare.verdict(prog_readings, ref, cell.limits)
     correct = correct and failed == 0
     for k, c in checks.items():

@@ -1,11 +1,13 @@
-"""Reduce a profiler trace to per-layer device times.
+"""Reduce a profiler trace to device times by layer and by named scope.
 
 Reads the ``.xplane.pb`` that ``jax.profiler.trace`` writes, with nothing
 but JAX. Device operations come from each TPU plane's ``XLA Ops`` line,
-named by their HLO instruction. The trace carries no name stack, and the
-program has no named spans of its own yet, so each instruction is put in
-a layer from the compiled step's HLO text: its ``op_name`` (the name stack
-JAX recorded) and the Python frames behind its ``stack_frame_id``.
+named by their HLO instruction. The trace carries no name stack, so each
+instruction is put in a layer, and in a scope, from the compiled step's
+HLO text: its ``op_name`` (the name stack JAX recorded) and the Python
+frames behind its ``stack_frame_id``.
+
+Layers:
 
 - ``collective``: all-gather, all-reduce, reduce-scatter, all-to-all,
   collective-permute and their async halves, by opcode;
@@ -20,22 +22,40 @@ JAX recorded) and the Python frames behind its ``stack_frame_id``.
 - ``optimizer``: raised from ``repro/optim``;
 - ``other``: the rest, and every operation of another program (the feed).
 
-Only innermost events count toward a layer (a loop's event holds its
-body's), and busy time is the union of all events. Host spans that the
-benchmark opens around its own calls (names starting ``bench.``) say what
-the host was doing in each idle gap of the device.
+Scopes: the innermost ``jax.named_scope`` an instruction's ``op_name``
+names, a scope being a name with a dot in it (the program's train step
+names ``train.grads``, ``train.round``, ``train.optimizer`` and, inside the
+round, ``spa.score``, ``spa.select``, ``spa.encode``, ``spa.exchange``,
+``spa.feedback``; a scope under a transform reads as ``jvp(mla.attn)``).
+A fusion carries its root's ``op_name``; where XLA merged instructions it
+joins their ``op_name``s with ``;`` and the last scope named counts. An
+instruction in no scope, or of another program, is in ``"none"``. Scope
+names are read from the text, not imported from the program, so a
+benchmark laid over a program without them reads no scope and does not
+fail.
+
+Only innermost events count toward a layer or a scope (a loop's event
+holds its body's), and busy time is the union of all events. Host spans
+that the benchmark opens around its own calls (names starting ``bench.``)
+say what the host was doing in each idle gap of the device.
 """
 from __future__ import annotations
 
 import collections
 import re
-from typing import Dict, List, NamedTuple, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 COLLECTIVE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute"
     r"|collective-broadcast)(-start|-done)?$")
 _INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\(.*?"
                     r"metadata=\{op_name=\"([^\"]*)\"(?: stack_frame_id=(\d+))?")
+_OP_NAME = re.compile(r"%([\w.\-]+) = .*?op_name=\"([^\"]*)\"")
+_SCOPE = re.compile(r"[A-Za-z_][\w\-]*(?:\.[\w\-]+)+")
+# the train step's stage scopes (``repro.core.stages``), as the readers
+# of ``bench/metrics`` name them
+STAGES = ("train.grads", "train.round", "train.optimizer", "spa.score",
+          "spa.select", "spa.encode", "spa.exchange", "spa.feedback")
 _ROUND_FUNCS = ("_spa_leaf", "make_sparsify_aggregate.<locals>.rounds",
                 "make_train_step.<locals>.train_step")
 _ROUND_FILES = ("/repro/core/compact.py", "/repro/comm/")
@@ -119,6 +139,19 @@ def hlo_layers(hlo_text: str) -> Dict[str, Tuple[str, str]]:
     return out
 
 
+def stage_of(op_name: str) -> str:
+    """The innermost named scope an ``op_name`` names, else ``"none"``."""
+    named = _SCOPE.findall(op_name)
+    return named[-1] if named else "none"
+
+
+def hlo_scopes(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> innermost named scope, for every instruction of
+    a compiled module's text that carries an ``op_name``."""
+    return {name: stage_of(op_name)
+            for name, op_name in _OP_NAME.findall(hlo_text)}
+
+
 def _device_index(plane_name: str) -> int:
     return int(re.match(r"/device:TPU:(\d+)", plane_name).group(1))
 
@@ -175,21 +208,24 @@ class Reduction(NamedTuple):
     chips: int
     busy_s: float  # union of operation intervals, averaged over chips
     layer_s: Dict[str, float]  # device seconds by layer, averaged over chips
+    scope_s: Dict[str, float]  # the same by innermost named scope
     top_ops: List[Tuple[str, float]]  # "layer:label" by seconds, over chips
     idle_by_host: List[Tuple[str, float]]  # chip 0's idle s by host span
 
 
 def reduce(ops: List[Op], spans: List[Span], chips: int,
            window_ns: Tuple[float, float],
-           layers: Dict[str, Tuple[str, str]]) -> Reduction:
+           layers: Dict[str, Tuple[str, str]],
+           scopes: Optional[Dict[str, str]] = None) -> Reduction:
     """Sum what started inside ``window_ns`` (the traced window on the
-    trace's clock) by layer and by operation; attribute chip 0's idle
-    gaps to the innermost host span that covers each gap's middle ("none"
-    where no span does)."""
+    trace's clock) by layer, by scope and by operation; attribute chip 0's
+    idle gaps to the innermost host span that covers each gap's middle
+    ("none" where no span does)."""
     lo, hi = window_ns
     inside = [o for o in ops if lo <= o.start_ns < hi]
     busy = 0.0
     layer: Dict[str, float] = collections.defaultdict(float)
+    scope: Dict[str, float] = collections.defaultdict(float)
     by_op: Dict[str, float] = collections.defaultdict(float)
     for d in range(chips):
         mine = [o for o in inside if o.device == d]
@@ -199,6 +235,7 @@ def reduce(ops: List[Op], spans: List[Span], chips: int,
             lay, label = layers.get(o.name, ("other", re.sub(
                 r"\.\d+$", "", o.name)))
             layer[lay] += o.dur_ns
+            scope[(scopes or {}).get(o.name, "none")] += o.dur_ns
             by_op[f"{lay}:{label}"] += o.dur_ns
     idle: Dict[str, float] = collections.defaultdict(float)
     cursor = lo
@@ -217,6 +254,7 @@ def reduce(ops: List[Op], spans: List[Span], chips: int,
         chips=chips,
         busy_s=busy / chips / 1e9,
         layer_s={k: v / chips / 1e9 for k, v in layer.items()},
+        scope_s={k: v / chips / 1e9 for k, v in scope.items()},
         top_ops=[(k, v / chips / 1e9) for k, v in top],
         idle_by_host=[(k, v / 1e9) for k, v in gaps],
     )
