@@ -40,7 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
     use_compile_cache()
-    cell = cell_lib.load_cell(args.workload)
+    cell = cell_lib.load_cell(args.workload, with_limits=False)
     devices = tpu_devices(cell.chips)
     m, traffic = cell.config["model"], cell.traffic
     out = open(args.out, "a") if args.out else None

@@ -39,7 +39,9 @@ class Cell:
     chips: int
     config: Dict[str, Any]  # configs/<config>.json
     model: ModuleType  # configs/<config>.py: reference, init, FLOPs
-    traffic: Dict[str, Any]  # traffic/<mix>.json
+    # traffic/<mix>.json: per chip, and its "workers" (one a chip; 1
+    # where the mix names none), so the mix states the global batch
+    traffic: Dict[str, Any]
     limits: Dict[str, float]  # limits/<workload>.json
     end_to_end: List[Dict[str, Any]]  # this cell's end-to-end metrics
     per_layer: List[Dict[str, Any]]  # this cell's per-layer metrics
@@ -51,9 +53,12 @@ def _applies(metric: Dict[str, Any], workload: str) -> bool:
 
 
 def load_cell(workload: str, root: pathlib.Path = ROOT,
-              bench_dir: pathlib.Path = BENCH_DIR) -> Cell:
+              bench_dir: pathlib.Path = BENCH_DIR,
+              with_limits: bool = True) -> Cell:
     """The cell named ``workload``; raises KeyError or OSError where the
-    benchmark does not define it completely."""
+    benchmark does not define it completely, and ValueError where its chips
+    are not its mix's workers. ``with_limits=False`` leaves the limits
+    empty, for ``calibrate.py``, whose readings set them."""
     spec = _read_json(root / "BENCHMARK.json")
     entries = {w["name"]: w for w in spec["workloads"]}
     if workload not in entries:
@@ -64,13 +69,19 @@ def load_cell(workload: str, root: pathlib.Path = ROOT,
     cfg_entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
     cfg_file = root / cfg_entry["file"]
     per_layer = [m for m in spec["per_layer"] if _applies(m, workload)]
+    traffic = _read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
+    if traffic.get("workers", 1) != int(w["chips"]):
+        raise ValueError(
+            f"{workload} runs on {w['chips']} chips, but its mix "
+            f"{w['traffic']!r} is for {traffic.get('workers', 1)} workers")
     return Cell(
         name=workload,
         chips=int(w["chips"]),
         config=_read_json(cfg_file),
         model=load_module(cfg_file.with_suffix(".py")),
-        traffic=_read_json(bench_dir / "traffic" / f"{w['traffic']}.json"),
-        limits=_read_json(bench_dir / "limits" / f"{workload}.json"),
+        traffic=traffic,
+        limits=(_read_json(bench_dir / "limits" / f"{workload}.json")
+                if with_limits else {}),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, workload)],
         per_layer=per_layer,
         readers={

@@ -11,7 +11,9 @@ import jax.numpy as jnp
 
 
 def unchanged_state(train_step):
-    """A step that returns its state unchanged."""
+    """A step that returns its state unchanged (where the program donates
+    its state, the outputs alias the inputs: the same buffers, handed
+    back)."""
     def step(params, opt_state, sp_state, batch):
         metrics = train_step(params, opt_state, sp_state, batch)[3]
         return params, opt_state, sp_state, metrics

@@ -2,14 +2,16 @@
 builds, with the benchmark's seeded weights and feed.
 
 What is taken from the program: ``DistConfig``, ``assemble``, the step it
-returns, ``init_sparsifier_state``, the optimizer's ``init``, the mesh, and
-``comm_round_bytes``. The step is jitted as ``train.main`` jits it: state
-out-shardings equal to the in-shardings, no donation.
+returns, ``init_sparsifier_state``, the optimizer's ``init``, the mesh,
+``comm_round_bytes``, and the arguments the program declares donated
+(``Assembled.donate_argnums``; none where it declares nothing). The step
+is jitted as the program declares: state out-shardings equal to the
+in-shardings, and exactly the declared arguments donated.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, NamedTuple, Tuple
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -37,6 +39,9 @@ class Program(NamedTuple):
     n_workers: int
     wire_bytes: int  # payload bytes per worker per round, from the plan
     cfg: ModelConfig
+    # the step's arguments the program declares donated: () or a subset
+    # of (0, 1, 2), the state; a donated argument is gone after the call
+    donate_argnums: Tuple[int, ...] = ()
 
 
 def dist_config(traffic, dp_axes) -> DistConfig:
@@ -74,6 +79,7 @@ def build(model, config, traffic, devices) -> Program:
     opt = make_optimizer(dist.optimizer)
     W = len(devices)
     replicated = NamedSharding(mesh, P())
+    donate = tuple(getattr(asm, "donate_argnums", ()))
 
     def init_state(seed: int):
         params = jax.jit(
@@ -94,15 +100,17 @@ def build(model, config, traffic, devices) -> Program:
                           seq=traffic["seq"]),
         out_shardings=NamedSharding(mesh, P("data")))
     _, wire = comm_round_bytes(asm.plan, dist, mesh)
-    return Program(asm.train_step, init_state, batch, mesh, W, int(wire), cfg)
+    return Program(asm.train_step, init_state, batch, mesh, W, int(wire), cfg,
+                   donate)
 
 
 def compile_step(prog: Program, state, batch, train_step=None):
-    """Jit the step as ``train.main`` does (state handed back where it came
-    from, metrics replicated, nothing donated) and compile it for this
-    state and batch. ``train_step`` replaces the program's step (tests
-    plant faults this way)."""
+    """Jit the step as the program declares it (state handed back where it
+    came from, metrics replicated, ``prog.donate_argnums`` donated) and
+    compile it for this state and batch. ``train_step`` replaces the
+    program's step (tests plant faults this way) and is jitted alike."""
     sh = jax.tree.map(lambda x: x.sharding, state)
     jitted = jax.jit(train_step or prog.step,
-                     out_shardings=(*sh, NamedSharding(prog.mesh, P())))
+                     out_shardings=(*sh, NamedSharding(prog.mesh, P())),
+                     donate_argnums=prog.donate_argnums)
     return jitted.lower(*state, batch).compile()

@@ -3,7 +3,8 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up builds the program's train step for the cell, makes its weights
-and state on the device from the seed, compiles the step and drives it
+and state on the device from the seed, compiles the step (jitted as the
+program declares, donating what it declares donated) and drives it
 through its first three steps (the ones the reference follows). The
 window then dispatches steps back to back for ``--seconds`` and ends on
 ``block_until_ready``. ``--trace 1`` runs the window (at most
@@ -117,7 +118,9 @@ def first_steps(cell: cell_lib.Cell, prog: program.Program, seed: int,
                 step=None, train_step=None):
     """Make the state from ``seed``, compile the step where ``step`` is
     None, and drive it through the first ``SETUP_STEPS`` steps. Returns
-    (step, state, the program's ``reference.Readings``)."""
+    (step, state, the program's ``reference.Readings``). Each step may
+    consume the state it is given (donation), so only the state it returns
+    is read, and the initial weights are made again from the seed."""
     m, b1 = cell.config["model"], cell.traffic["optimizer"]["b1"]
     key = feed.batches_key(seed)
     state = prog.init_state(seed)

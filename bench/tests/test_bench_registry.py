@@ -23,6 +23,38 @@ def test_every_cell_loads(workload):
         assert k in cell.traffic
 
 
+def test_four_chip_cell_resolves():
+    """The cell whose payloads cross chips: whisper-tiny's configuration,
+    the 40 x 448 RegTop-k mix of one chip for four workers (a mix of its
+    own: a pair of configuration and mix names one cell), limits of its
+    own, and the wire's reader besides the round's."""
+    cell = cell_lib.load_cell("whisper-tiny.regtopk.4chip")
+    one = cell_lib.load_cell("whisper-tiny.regtopk.1chip")
+    assert cell.chips == 4 and cell.traffic["workers"] == 4
+    assert cell.config == one.config
+    assert cell.traffic == dict(one.traffic, workers=4)
+    assert cell.traffic["batch_per_chip"] == 40 and cell.traffic["seq"] == 448
+    assert cell.traffic["sparsifier"]["kind"] == "regtopk"
+    assert cell.traffic["collective"] == "sparse_allgather"
+    assert set(cell.limits) == {"loss_gap", "grad_gap", "delta_gap"}
+    assert set(cell.readers) == set(one.readers) | {"exchange_ms"}
+
+
+@pytest.mark.parametrize("workload,traffic", [
+    ("whisper-tiny.regtopk.4chip", "regtopk.b40x448"),
+    ("whisper-tiny.regtopk.1chip", "regtopk.b40x448.dp4"),
+])
+def test_cell_whose_chips_are_not_its_mix_workers_is_refused(
+        tmp_path, workload, traffic):
+    spec = json.loads(json.dumps(SPEC))
+    for w in spec["workloads"]:
+        if w["name"] == workload:
+            w["traffic"] = traffic
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    with pytest.raises(ValueError, match="workers"):
+        cell_lib.load_cell(workload, root=tmp_path)
+
+
 def test_cell_added_as_files_alone_is_found(tmp_path):
     """A new configuration, traffic mix, per-layer metric and cell, added
     as files and entries only, load without a change to the harness."""

@@ -1,21 +1,24 @@
 """One run of a cell, driven on the CPU at a tiny size past the look for a
-chip: its result line, and that ``correct`` turns false when the timed
-path is broken underneath or the control takes the program's place."""
+chip: its result line, that ``correct`` turns false when the timed path
+is broken underneath or the control takes the program's place, and that
+the step is donated exactly as the program declares."""
 import json
 import os
 import subprocess
 import sys
 import time
+import types
 
 import bench_tiny
 import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import compare, faults, reference
+from bench import compare, faults, program, reference
 from bench import run as run_lib
 
 LINE_KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
+SPEC = json.loads((bench_tiny.ROOT / "BENCHMARK.json").read_text())
 
 
 def _run(cell, fault=None, seed=2**33 + 5):
@@ -75,7 +78,7 @@ def test_no_exchange_between_chips_is_not_correct():
         "import sys, time, json; sys.path.insert(0, sys.argv[1]);"
         "import bench_tiny, jax;"
         "from bench import run, faults;"
-        "cell = bench_tiny.tiny_cell('whisper-tiny', 'regtopk.b40x448',"
+        "cell = bench_tiny.tiny_cell('whisper-tiny', 'regtopk.b40x448.dp4',"
         " 'whisper-tiny.regtopk.1chip', chips=4);"
         "ok = run.run_cell(cell, 9, 0.2, False, jax.devices()[:4])['correct'];"
         "ctx = faults.no_exchange(); ctx.__enter__();"
@@ -102,3 +105,78 @@ def test_without_a_tpu_exits_nonzero_and_prints_no_result():
     assert res.returncode != 0
     assert res.stdout.strip() == ""
     assert "no TPU" in res.stderr
+
+
+def _declare_donation(monkeypatch):
+    """The program's ``assemble`` declares its state donated, as a program
+    may, through a wrapped ``Assembled``; no program file changes."""
+    assemble = program.assemble
+
+    def declared(*args, **kwargs):
+        asm = assemble(*args, **kwargs)
+        return types.SimpleNamespace(**asm._asdict(), donate_argnums=(0, 1, 2))
+
+    monkeypatch.setattr(program, "assemble", declared)
+
+
+@pytest.fixture
+def donating(monkeypatch):
+    _declare_donation(monkeypatch)
+
+
+def _tiny_program(cell):
+    return program.build(cell.model, cell.config, cell.traffic,
+                         jax.devices()[:1])
+
+
+def test_declared_donation_runs_and_reads_as_undonated(monkeypatch):
+    """With the state donated, set-up, the window and the reference touch
+    no deleted array, the step consumes its state, and ``correct``'s
+    numbers are the undonated run's, bit for bit, on the same seed."""
+    cell = _cell()
+    plain = _run(cell)
+    _declare_donation(monkeypatch)
+    donated = _run(cell)
+    assert donated["correct"] is True, donated["checks"]
+    assert donated["checks"] == plain["checks"]
+    prog = _tiny_program(cell)
+    assert prog.donate_argnums == (0, 1, 2)
+    state = prog.init_state(5)
+    batch = prog.batch(run_lib.feed.batches_key(5), 0)
+    step = program.compile_step(prog, state, batch)
+    assert step.memory_analysis().alias_size_in_bytes > 0
+    step(*state, batch)
+    assert all(x.is_deleted() for x in jax.tree.leaves(state))
+
+
+def test_unchanged_state_fault_is_caught_when_donated(donating):
+    """The fault's outputs are its donated inputs, handed back."""
+    out = _run(_cell(), fault=faults.unchanged_state)
+    assert out["correct"] is False, out["checks"]
+    assert out["checks"]["delta_gap"]["value"] == pytest.approx(1.0)
+
+
+def test_donated_initial_state_holds_each_buffer_once(donating):
+    """A donated call consumes each buffer once, so no two leaves of the
+    initial state may share one; a program whose init shares a buffer
+    fails on its first donated step, and this test names it first."""
+    prog = _tiny_program(_cell())
+    buffers = [(s.device.id, s.data.unsafe_buffer_pointer())
+               for x in jax.tree.leaves(prog.init_state(7))
+               for s in x.addressable_shards]
+    assert len(set(buffers)) == len(buffers)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in SPEC["configs"]])
+def test_undeclared_step_has_no_input_output_alias(config):
+    """Where the program declares nothing, nothing is donated: the step
+    compiles as the parent's did, with no argument aliased to an output."""
+    traffic = {w["config"]: w["traffic"] for w in SPEC["workloads"]}[config]
+    cell = _cell(config, traffic)
+    prog = _tiny_program(cell)
+    assert prog.donate_argnums == ()
+    state = prog.init_state(1)
+    step = program.compile_step(prog, state,
+                                prog.batch(run_lib.feed.batches_key(1), 0))
+    assert step.memory_analysis().alias_size_in_bytes == 0
+    assert "input_output_alias" not in step.as_text().split("\n", 1)[0]

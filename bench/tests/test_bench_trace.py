@@ -71,6 +71,23 @@ def test_hlo_layers_by_opcode_name_stack_and_frames():
     assert lay["tuple.7"][0] == "other"
 
 
+def test_collective_without_op_name_is_in_the_collective_layer():
+    """XLA's combiner leaves the combined collective no metadata (the
+    four-chip step's payload all-gathers compile to such all-reduces); it
+    is placed by its opcode, and other instructions without an op_name
+    stay out of the map."""
+    hlo = HLO.replace("ENTRY %main {\n", "ENTRY %main {\n"
+        "  %all-reduce.64 = (f32[8]{0:T(1024)S(1)}, s32[8]{0:T(1024)S(1)}) "
+        "all-reduce(%a, %b), channel_id=3, replica_groups=[1,4]<=[4], "
+        "to_apply=%add, backend_config={\"flag\":\"(x)\"}\n"
+        "  %copy.9 = f32[8]{0} copy(%p)\n")
+    lay = T.hlo_layers(hlo)
+    assert lay["all-reduce.64"] == ("collective", "all-reduce")
+    assert "copy.9" not in lay
+    assert {k: v for k, v in lay.items() if k != "all-reduce.64"} == (
+        T.hlo_layers(HLO))
+
+
 def _op(dev, name, start_ms, dur_ms):
     return T.Op(dev, name, start_ms * MS, dur_ms * MS)
 
@@ -230,3 +247,29 @@ def test_readers_over_a_reduction():
         "mamba2-780m-16L.regtopk.1chip").readers) == set(got)
     assert set(cell_lib.load_cell("whisper-tiny.dense.1chip").readers) == (
         set(got) - {"round_ms", "select_ms", "score_ms", "feedback_ms"})
+
+
+def test_exchange_ms_reads_the_collectives_and_nothing_on_one_chip(tmp_path):
+    """``exchange_ms`` reads the collective layer's time a step, averaged
+    over the chips, and None from the recorded one-chip trace, which runs
+    no collective."""
+    from bench import cell as cell_lib
+    from bench import run
+
+    reader = cell_lib.load_cell("whisper-tiny.regtopk.4chip").readers[
+        "exchange_ms"]
+    ops = [_op(0, "all-gather.3", 0, 2), _op(1, "all-gather.3", 0, 4),
+           _op(0, "fusion.1", 2, 6), _op(1, "fusion.1", 4, 4)]
+    layers = {"all-gather.3": ("collective", "all_gather"),
+              "fusion.1": ("fwd_bwd", "dot_general")}
+    red = T.reduce(ops, [], 2, (0, 10 * MS), layers)
+    ctx = run.LayerContext(
+        trace=red, steps=2, window_s=0.01, chips=2, flops_per_step=1.0,
+        peak_flops=197e12, hbm_bytes_per_s=819e9, wire_bytes=0, model=None,
+        sizes={}, traffic={})
+    assert reader.read(ctx) == pytest.approx(1.5)  # (2 + 4) / 2 chips / 2
+    ops, spans, hlo = recorded("tiny_whisper_scoped_v5e", tmp_path)
+    alone = T.reduce(ops, spans, 1, T.window_of(spans), T.hlo_layers(hlo),
+                     T.hlo_scopes(hlo))
+    assert "collective" not in alone.layer_s
+    assert reader.read(ctx._replace(trace=alone, chips=1)) is None

@@ -10,7 +10,9 @@ frames behind its ``stack_frame_id``.
 Layers:
 
 - ``collective``: all-gather, all-reduce, reduce-scatter, all-to-all,
-  collective-permute and their async halves, by opcode;
+  collective-permute and their async halves, by opcode, with or without
+  an ``op_name`` (XLA's combiner drops it: on four v5e chips the round's
+  all-gathers compile to two combined all-reduces that carry none);
 - ``fwd_bwd``: under ``jvp`` or ``transpose`` (the model's forward and
   backward passes);
 - ``round``: raised from the sparsify-and-aggregate round (``_spa_leaf``
@@ -48,8 +50,8 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 COLLECTIVE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute"
     r"|collective-broadcast)(-start|-done)?$")
-_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\(.*?"
-                    r"metadata=\{op_name=\"([^\"]*)\"(?: stack_frame_id=(\d+))?")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\(")
+_META = re.compile(r"metadata=\{op_name=\"([^\"]*)\"(?: stack_frame_id=(\d+))?")
 _OP_NAME = re.compile(r"%([\w.\-]+) = .*?op_name=\"([^\"]*)\"")
 _SCOPE = re.compile(r"[A-Za-z_][\w\-]*(?:\.[\w\-]+)+")
 # the train step's stage scopes (``repro.core.stages``), as the readers
@@ -125,14 +127,20 @@ def layer_of(opcode: str, op_name: str, frames) -> str:
 
 def hlo_layers(hlo_text: str) -> Dict[str, Tuple[str, str]]:
     """Instruction name -> (layer, label) for every instruction of a
-    compiled module's text; the label is the last part of its op_name."""
+    compiled module's text that carries an ``op_name``, and for every
+    collective; the label is the last part of its op_name, else its
+    opcode."""
     chain = _frames(hlo_text)
     out = {}
     for line in hlo_text.splitlines():
         m = _INSTR.match(line)
         if not m:
             continue
-        name, opcode, op_name, frame = m.groups()
+        name, opcode = m.groups()
+        meta = _META.search(line)
+        if not meta and not COLLECTIVE.match(opcode):
+            continue
+        op_name, frame = meta.groups() if meta else ("", None)
         frames = chain(int(frame)) if frame else []
         label = op_name.rsplit("/", 1)[-1] or opcode
         out[name] = (layer_of(opcode, op_name, frames), label)
